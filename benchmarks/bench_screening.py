@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     PathDriver,
     fista_solve,
@@ -1114,6 +1115,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny-instance engine check (CI); no trajectory write")
     args = ap.parse_args()
+    enable_compile_cache()
     rows = run(smoke=args.smoke)
     print("\nname,us_per_call,derived")
     for name, us, derived in rows:

@@ -28,17 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-    """Thin compat wrapper: jax>=0.8 ``jax.shard_map`` (check_vma keyword) or
-    the older ``jax.experimental.shard_map`` (check_rep keyword)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_rep)
-
+from .linalg import dot
 from .screening import (
     SAFE_TAU,
     FeatureReductions,
@@ -154,14 +144,15 @@ def sample_surplus_sharded(
     def local(x_blk, y_blk, w_blk, up_blk):
         if have_model:
             # fused 2-row reduction over this shard's feature rows, one psum
-            part = jnp.stack([x_blk.T @ w_blk, jnp.sum(x_blk * x_blk, axis=0)])
+            part = jnp.stack([dot(x_blk.T, w_blk),
+                              jnp.sum(x_blk * x_blk, axis=0)])
             part = jax.lax.psum(part, "model")
             u1, x_sq = part[0] + b, part[1]
         else:
             # feature axis whole on this shard: identical arithmetic to the
             # local oracle (no stack/psum detour), so the bitwise-equality
             # contract of margin_surplus_core extends to the reductions too
-            u1 = x_blk.T @ w_blk + b
+            u1 = dot(x_blk.T, w_blk) + b
             x_sq = jnp.sum(x_blk * x_blk, axis=0)
         surplus = margin_surplus_core(
             u1, y_blk, x_sq, dw, db,
@@ -171,12 +162,12 @@ def sample_surplus_sharded(
         return surplus, u1
 
     up = u_prev if has_history else jnp.zeros_like(y)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", *data_axes), P(*data_axes), P("model"),
                   P(*data_axes)),
         out_specs=(P(*data_axes), P(*data_axes)),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(X, y, w, up)
 
@@ -211,7 +202,7 @@ def screen_sharded(
     def local(x_blk, y_blk, th_blk):
         # local partial reductions over this shard's sample columns
         rhs = jnp.stack([y_blk * th_blk, y_blk, jnp.ones_like(y_blk)], axis=1)
-        d = x_blk @ rhs                       # (m_loc, 3)
+        d = dot(x_blk, rhs)                       # (m_loc, 3)
         d_sq = jnp.sum(x_blk * x_blk, axis=1)  # (m_loc,)
         packed = jnp.concatenate([d, d_sq[:, None]], axis=1)
         packed = jax.lax.psum(packed, data_axes)
@@ -222,8 +213,8 @@ def screen_sharded(
             [
                 jnp.sum(y_blk),
                 jnp.sum(th_blk),
-                th_blk @ y_blk,
-                th_blk @ th_blk,
+                dot(th_blk, y_blk),
+                dot(th_blk, th_blk),
                 jnp.asarray(n_loc, jnp.float32),
             ]
         )
@@ -243,9 +234,9 @@ def screen_sharded(
         P(*data_axes),
         P(*data_axes),
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=specs_in, out_specs=(P("model"), P("model")),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(X, y, theta1)
 
@@ -322,13 +313,13 @@ def fista_sharded(
 
     def local(x_blk, y_blk, sm_blk, fm_blk, w_blk, b_scalar, L_in):
         def margins(w):
-            part = x_blk.T @ w  # (n_loc,)
+            part = dot(x_blk.T, w)  # (n_loc,)
             return jax.lax.psum(part, "model")
 
         def grad(w, b):
             u = margins(w) + b
             xi = sm_blk * jnp.maximum(0.0, 1.0 - y_blk * u)
-            gw = -(x_blk @ (y_blk * xi))
+            gw = -dot(x_blk, y_blk * xi)
             gw = jax.lax.psum(gw, data_axes)
             gb = -jnp.sum(y_blk * xi)
             gb = jax.lax.psum(gb, (*data_axes, "model")) / jax.lax.psum(
@@ -352,17 +343,17 @@ def fista_sharded(
         else:
             # power iteration for L (sharded)
             def pow_body(v, _):
-                nrm = jnp.sqrt(jax.lax.psum(v @ v, data_axes))
+                nrm = jnp.sqrt(jax.lax.psum(dot(v, v), data_axes))
                 v = v / jnp.maximum(nrm, 1e-30)
-                u_w = jax.lax.psum(x_blk @ v, data_axes)  # X@v reduces over data
+                u_w = jax.lax.psum(dot(x_blk, v), data_axes)  # X@v reduces over data
                 u_b = jax.lax.psum(jnp.sum(v), data_axes)
-                vn = x_blk.T @ u_w
+                vn = dot(x_blk.T, u_w)
                 vn = jax.lax.psum(vn, "model") + u_b
                 return vn, None
 
             v0 = jnp.cos(jnp.arange(y_blk.shape[0], dtype=jnp.float32) + 1.0)
             v, _ = jax.lax.scan(pow_body, v0, None, length=30)
-            Lc = jnp.sqrt(jax.lax.psum(v @ v, data_axes))
+            Lc = jnp.sqrt(jax.lax.psum(dot(v, v), data_axes))
         Lc = jnp.maximum(Lc * 1.01, 1e-12)
         inv_L = 1.0 / Lc
 
@@ -438,8 +429,8 @@ def fista_sharded(
         # theta-independent bound reductions over live samples (one sweep +
         # one 3-scalar psum, shared by every refresh — cf. screen_sharded)
         stat = jnp.stack([y_blk * sm_blk, sm_blk], axis=1)       # (n_loc, 2)
-        dd = x_blk @ stat                                         # (m_loc, 2)
-        d_sq = (x_blk * x_blk) @ sm_blk
+        dd = dot(x_blk, stat)                                    # (m_loc, 2)
+        d_sq = dot(x_blk * x_blk, sm_blk)
         dd = jax.lax.psum(jnp.concatenate([dd, d_sq[:, None]], axis=1), data_axes)
         d_one_blk, d_y_blk, d_sq_blk = dd[:, 0], dd[:, 1], dd[:, 2]
         sums = jax.lax.psum(
@@ -456,18 +447,18 @@ def fista_sharded(
             )
 
             def feas_body(alpha, _):
-                corr = jax.lax.psum(x_blk @ (y_blk * alpha), data_axes)
+                corr = jax.lax.psum(dot(x_blk, y_blk * alpha), data_axes)
                 mx = jax.lax.pmax(jnp.max(jnp.abs(corr)), "model")
                 alpha = alpha * jnp.minimum(1.0, lam / jnp.maximum(mx, 1e-30))
-                ay = jax.lax.psum(alpha @ y_blk, data_axes)
+                ay = jax.lax.psum(dot(alpha, y_blk), data_axes)
                 return sm_blk * jnp.maximum(0.0, alpha - ay / n_tot * y_blk), None
 
             alpha, _ = jax.lax.scan(feas_body, xi, None, length=n_feas_iters)
-            corr = jax.lax.psum(x_blk @ (y_blk * alpha), data_axes)
+            corr = jax.lax.psum(dot(x_blk, y_blk * alpha), data_axes)
             mx = jax.lax.pmax(jnp.max(jnp.abs(corr)), "model")
             alpha = alpha * jnp.minimum(1.0, lam / jnp.maximum(mx, 1e-30))
             stats = jax.lax.psum(
-                jnp.stack([jnp.sum(alpha), jnp.sum(alpha * alpha), alpha @ y_blk]),
+                jnp.stack([jnp.sum(alpha), jnp.sum(alpha * alpha), dot(alpha, y_blk)]),
                 data_axes,
             )
             gap = jnp.maximum(p_obj - (stats[0] - 0.5 * stats[1]), 0.0)
@@ -496,12 +487,12 @@ def fista_sharded(
             # refresh: certify the region at the current iterate, re-screen
             theta, delta, gap = gap_certificate(w, b)
             th_stats = jax.lax.psum(
-                jnp.stack([jnp.sum(theta), theta @ y_blk, theta @ theta]),
+                jnp.stack([jnp.sum(theta), dot(theta, y_blk), dot(theta, theta)]),
                 data_axes,
             )
             sh = _shared_from_stats(lam, lam, one_y, th_stats[0], th_stats[1],
                                     th_stats[2], n_tot, delta=delta)
-            d_theta_blk = jax.lax.psum(x_blk @ (y_blk * theta), data_axes)
+            d_theta_blk = jax.lax.psum(dot(x_blk, y_blk * theta), data_axes)
             red = FeatureReductions(d_theta=d_theta_blk, d_one=d_one_blk,
                                     d_y=d_y_blk, d_sq=d_sq_blk)
             # min of the VI cap and the GAP-sphere bound — see
@@ -553,7 +544,7 @@ def fista_sharded(
     b0 = jnp.asarray(b0, jnp.float32)
 
     scalar_out = (P(), P(), P(), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("model", *data_axes), P(*data_axes), P(*data_axes),
@@ -561,7 +552,7 @@ def fista_sharded(
         out_specs=(P("model"), *scalar_out)
         if not dynamic
         else (P("model"), *scalar_out, P("model"), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(X, y, jnp.asarray(sample_mask, jnp.float32),
              jnp.asarray(feature_mask, jnp.float32), w0, b0,

@@ -23,6 +23,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .linalg import dot
+
 __all__ = [
     "safe_theta_and_delta",
     "bias_at_lambda_max",
@@ -37,48 +39,59 @@ __all__ = [
 ]
 
 
-def bias_at_lambda_max(y: jax.Array) -> jax.Array:
-    """Optimal bias when ``w = 0``: ``b* = (n+ - n-)/n`` (paper Sec. 4)."""
-    return jnp.mean(y)
+def bias_at_lambda_max(y: jax.Array, sample_mask=None) -> jax.Array:
+    """Optimal bias when ``w = 0``: ``b* = (n+ - n-)/n`` (paper Sec. 4).
+
+    A 0/1 ``sample_mask`` marks the live samples of a zero-padded problem
+    (padded labels are 0): ``n`` is then the live count."""
+    if sample_mask is None:
+        return jnp.mean(y)
+    return jnp.sum(y * sample_mask) / jnp.sum(sample_mask)
 
 
-def lambda_max(X: jax.Array, y: jax.Array) -> jax.Array:
+def lambda_max(X: jax.Array, y: jax.Array, sample_mask=None) -> jax.Array:
     """Smallest ``lam`` such that ``w*(lam) = 0`` (paper Eq. 26).
 
     ``lambda_max = || sum_i (y_i - b*) x_i ||_inf = || X (y - b*) ||_inf``.
     Computed in the row-stable formulation (``screening.row_dot``) so the
     out-of-core ``sparse.lambda_max_stream`` — a max of per-chunk maxima —
     reproduces this value bitwise and both storages walk the *same*
-    default lambda grid.
+    default lambda grid. ``sample_mask`` as in :func:`bias_at_lambda_max`.
     """
     from .screening import row_dot  # local: keep dual.py dependency-light
 
-    b_star = bias_at_lambda_max(y)
-    moment = row_dot(X, y - b_star)
+    b_star = bias_at_lambda_max(y, sample_mask)
+    r = y - b_star
+    if sample_mask is not None:
+        r = r * sample_mask
+    moment = row_dot(X, r)
     return jnp.max(jnp.abs(moment))
 
 
 def first_features(X: jax.Array, y: jax.Array) -> jax.Array:
     """Index of the first feature to enter the model (paper Sec. 5)."""
     b_star = bias_at_lambda_max(y)
-    moment = X @ (y - b_star)
+    moment = dot(X, y - b_star)
     return jnp.argmax(jnp.abs(moment))
 
 
-def theta_at_lambda_max(y: jax.Array, lam_max: jax.Array) -> jax.Array:
+def theta_at_lambda_max(y: jax.Array, lam_max: jax.Array,
+                        sample_mask=None) -> jax.Array:
     """Closed-form dual point at ``lam_max`` (paper Eq. 20 with w=0).
 
     ``theta_i = max(0, 1 - y_i b*) / lam_max``; with ``b* in [-1, 1]`` the max
     is inactive, so ``theta_i = (1 - y_i b*) / lam_max`` and ``theta^T y = 0``
-    holds exactly.
+    holds exactly. ``sample_mask`` as in :func:`bias_at_lambda_max` (padded
+    samples get ``theta_i = 0``).
     """
-    b_star = bias_at_lambda_max(y)
-    return (1.0 - y * b_star) / lam_max
+    b_star = bias_at_lambda_max(y, sample_mask)
+    theta = (1.0 - y * b_star) / lam_max
+    return theta if sample_mask is None else theta * sample_mask
 
 
 def xi_from_primal(X: jax.Array, y: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     """Hinge slack ``xi_i = max(0, 1 - y_i (w^T x_i + b))`` (paper Eq. 20)."""
-    margins = y * (X.T @ w + b)
+    margins = y * (dot(X.T, w) + b)
     return jnp.maximum(0.0, 1.0 - margins)
 
 
@@ -139,15 +152,15 @@ def duality_gap_estimate(
     n = y.shape[0]
 
     def body(alpha, _):
-        corr = X @ (y * alpha)  # fhat_j^T alpha for all j
+        corr = dot(X, y * alpha)  # fhat_j^T alpha for all j
         scale = jnp.minimum(1.0, lam / jnp.maximum(jnp.max(jnp.abs(corr)), 1e-30))
         alpha = alpha * scale
-        alpha = jnp.maximum(0.0, alpha - (alpha @ y) / n * y)
+        alpha = jnp.maximum(0.0, alpha - dot(alpha, y) / n * y)
         return alpha, None
 
     alpha, _ = jax.lax.scan(body, alpha, None, length=n_feas_iters)
     # final rescale so the inequality constraints hold for sure
-    corr = X @ (y * alpha)
+    corr = dot(X, y * alpha)
     scale = jnp.minimum(1.0, lam / jnp.maximum(jnp.max(jnp.abs(corr)), 1e-30))
     alpha = alpha * scale
     d_obj = dual_objective(alpha)
@@ -167,6 +180,7 @@ def safe_theta_and_delta(
     """
     est = duality_gap_estimate(X, y, w, b, lam, n_feas_iters=n_feas_iters)
     n = y.shape[0]
-    eq_resid = jnp.abs(est.alpha @ y) / jnp.sqrt(jnp.asarray(float(n), y.dtype))
+    eq_resid = (jnp.abs(dot(est.alpha, y))
+                / jnp.sqrt(jnp.asarray(float(n), y.dtype)))
     delta = (est.theta_radius + 2.0 * eq_resid) / lam
     return est.alpha / lam, delta

@@ -17,9 +17,9 @@ re-compiles of the per-step certificate, and per-solve Lipschitz power
 iterations. This module is the engine for that regime (``engine="scan"``):
 
 * the lambda grid is walked by a single jitted ``lax.scan`` whose carry is
-  ``(w, b, theta, delta, lam_prev, keep_mask)`` — XLA aliases the carry
-  buffers in place (donated, no copies), and nothing syncs to the host until
-  the final stacked ``PathResult`` is pulled once at the end;
+  ``(w, b, theta, delta, lam_prev, keep_mask)`` — XLA updates the carry
+  buffers in place, and nothing syncs to the host until the final stacked
+  ``PathResult`` is pulled once at the end;
 * each scan step rebuilds the rule stack's screening region(s) from the
   carried anchor (``screening.AnchorStats`` + the pure rule programs of
   ``rules/programs.py``), evaluates the feature bounds with the
@@ -108,6 +108,7 @@ from repro.obs import trace as obs_trace
 from repro.obs.path_trace import build_path_trace
 
 from .dual import bias_at_lambda_max, lambda_max, theta_at_lambda_max
+from .linalg import dot
 # _validate_grid shared with the host driver: a grid-validation change
 # applied to one engine must never leave the other accepting what the
 # first rejects
@@ -130,6 +131,7 @@ from .solver import (
     _dynamic_run,
     _resolve_guards,
     _resolve_pallas,
+    _sweep_operand,
     fista_run,
     gap_theta_delta,
     lipschitz_estimate,
@@ -220,9 +222,9 @@ def _batched_statics(X, y, sm, shared_x: bool):
     count — never the padded width.
     """
     def one(Xe, ye, sme):
-        d_one = Xe @ ye
-        d_y = Xe @ sme
-        d_sq = (Xe * Xe) @ sme
+        d_one = dot(Xe, ye)
+        d_y = dot(Xe, sme)
+        d_sq = dot(Xe * Xe, sme)
         return (d_one, d_y, d_sq, jnp.sum(ye * sme), jnp.sum(sme))
 
     return one(X, y, sm) if shared_x else jax.vmap(one)(X, y, sm)
@@ -282,8 +284,8 @@ def _batched_path_step(
         def anchor(lam_a, th_a, de_a):
             return AnchorStats(
                 lam=lam_a, delta=de_a, theta_dot_one=jnp.sum(th_a),
-                theta_dot_y=th_a @ ye, theta_sq=th_a @ th_a,
-                d_theta=Xe @ (ye * th_a),
+                theta_dot_y=dot(th_a, ye), theta_sq=dot(th_a, th_a),
+                d_theta=dot(Xe, ye * th_a),
             )
 
         anchors = (anchor(lp, th, de),)
@@ -521,6 +523,7 @@ def _path_scan_program(
     col: Collectives = LOCAL,
     n_feas_iters: int = 8,
     guards: bool = False,
+    sm: Optional[jax.Array] = None,
 ) -> ScanPathOutputs:
     """The traced whole-path program (one ``lax.scan`` over the grid).
 
@@ -531,7 +534,10 @@ def _path_scan_program(
     ``lambda_max`` in the standard entry points). Under ``shard_map`` the
     shapes here are the per-device blocks and ``col`` binds the reductions
     to the mesh (compact reduction requires global row indices, so it is
-    local-only — wrappers enforce ``reduce="mask"`` when sharded).
+    local-only — wrappers enforce ``reduce="mask"`` when sharded). ``sm``
+    (0/1 over samples, optional) marks the live columns of a zero-padded
+    ``X`` (the sharded wrapper pads samples to the mesh); the anchor must
+    already be masked.
     """
     m, n = X.shape
     dt = X.dtype
@@ -547,18 +553,22 @@ def _path_scan_program(
         )
 
     if L is None:
-        L = lipschitz_estimate(X, col=col)
+        L = lipschitz_estimate(X, col=col, sample_mask=sm)
     L = jnp.maximum(L * 1.01, 1e-12)
     inv_L = 1.0 / L
+    # the Pallas sweeps' operand, padded once per path: every mask-branch
+    # solve reads it (compact solves pad their own gathered buffer)
+    X_sweep = _sweep_operand(X, use_pallas, col)
 
     # theta-independent screen reductions, hoisted out of the scan: per step
     # only the O(mn) ``X @ (y * theta)`` sweep remains (paper Sec. 6.4).
-    ones = jnp.ones((n,), dt)
-    d_one = col.psum_data(X @ y)          # fhat_j^T 1
-    d_y = col.psum_data(X @ ones)         # fhat_j^T y
+    ones = jnp.ones((n,), dt) if sm is None else sm
+    d_one = col.psum_data(dot(X, y))          # fhat_j^T 1
+    d_y = col.psum_data(dot(X, ones))         # fhat_j^T y
     d_sq = col.psum_data(jnp.sum(X * X, axis=1))
     one_y = col.psum_data(jnp.sum(y))
-    n_tot = col.psum_data(jnp.asarray(float(n), dt))
+    n_tot = col.psum_data(jnp.asarray(float(n), dt) if sm is None
+                          else jnp.sum(sm))
     m_tot = col.psum_model(jnp.asarray(float(m), dt)).astype(jnp.int32)
 
     progs = tuple(PROGRAMS[nm] for nm in rules) if screening else ()
@@ -572,9 +582,9 @@ def _path_scan_program(
         return AnchorStats(
             lam=lam_a, delta=delta_a,
             theta_dot_one=col.psum_data(jnp.sum(theta_a)),
-            theta_dot_y=col.psum_data(theta_a @ y),
-            theta_sq=col.psum_data(theta_a @ theta_a),
-            d_theta=col.psum_data(X @ (y * theta_a)),
+            theta_dot_y=col.psum_data(dot(theta_a, y)),
+            theta_sq=col.psum_data(dot(theta_a, theta_a)),
+            d_theta=col.psum_data(dot(X, y * theta_a)),
         )
 
     def step(carry, lam):
@@ -584,18 +594,18 @@ def _path_scan_program(
         else:
             w, b, theta, delta, lam_prev, fmask_prev = carry
 
-        def solve(Xs, ws, bs, fms, inv_Ls, vm):
+        def solve(Xs, ws, bs, fms, inv_Ls, vm, sweep_X=None):
             """Fused-FISTA (or dynamic segmented) solve on one reduction."""
             if dynamic:
                 return _dynamic_run(
-                    Xs, y, lam, ws, bs, inv_Ls, None, fms,
+                    Xs, y, lam, ws, bs, inv_Ls, sm, fms,
                     max_iters, tol, screen_every, tau, 4, use_pallas,
-                    valid_m=vm, guards=guards,
+                    valid_m=vm, guards=guards, sweep_X=sweep_X,
                 )
             return fista_run(
-                Xs, y, lam, ws, bs, inv_Ls, None, fms,
+                Xs, y, lam, ws, bs, inv_Ls, sm, fms,
                 max_iters, tol, use_pallas, col=col, valid_m=vm,
-                guards=guards,
+                guards=guards, sweep_X=sweep_X,
             )
 
         # -- sequential screen from the carried anchor(s) ------------------
@@ -632,7 +642,8 @@ def _path_scan_program(
         def inv_L_for(Xs):
             if exact_lipschitz:
                 return 1.0 / jnp.maximum(
-                    lipschitz_estimate(Xs, col=col) * 1.01, 1e-12)
+                    lipschitz_estimate(Xs, col=col, sample_mask=sm) * 1.01,
+                    1e-12)
             return inv_L
 
         def mask_branch(args):
@@ -640,7 +651,7 @@ def _path_scan_program(
             # inv_L_for ignores its operand unless exact_lipschitz (the
             # masked multiply is DCE'd then), mirroring the compact branch
             res = solve(X, w_ * fmask_, b_, fmask_,
-                        inv_L_for(X * fmask_[:, None]), None)
+                        inv_L_for(X * fmask_[:, None]), None, X_sweep)
             return (res.w, res.b, res.obj, jnp.asarray(res.n_iters, jnp.int32),
                     res.converged, res.u, jnp.asarray(res.health, jnp.int32))
 
@@ -655,13 +666,17 @@ def _path_scan_program(
                     jnp.arange(m, dtype=jnp.int32), mode="drop")
                 validf = (sel < m).astype(dt)
                 selc = jnp.minimum(sel, m - 1)
-                Xc = jnp.take(X, selc, axis=0) * validf[:, None]
+                # gathered from the padded sweep operand, so the kernels
+                # read the buffer as built (Xc is its logical slice)
+                Xc_s = jnp.take(X_sweep, selc, axis=0) * validf[:, None]
+                Xc = Xc_s[:, :n]
                 # every gathered row is a certified keep, so the buffer's
                 # live mask IS the validity mask; w already respects fmask
                 # on gathered rows (screened rows are not in the buffer)
                 w0_c = jnp.take(w_, selc) * validf
                 vcount = jnp.sum(fmask_).astype(jnp.int32)
-                res = solve(Xc, w0_c, b_, validf, inv_L_for(Xc), vcount)
+                res = solve(Xc, w0_c, b_, validf, inv_L_for(Xc), vcount,
+                            _sweep_operand(Xc_s, use_pallas, col))
                 w_full = jnp.zeros((m,), dt).at[selc].add(res.w * validf)
                 return (w_full, res.b, res.obj,
                         jnp.asarray(res.n_iters, jnp.int32), res.converged,
@@ -688,7 +703,7 @@ def _path_scan_program(
         # feature — but the margin sweep rides the solver's carried u)
         with jax.named_scope("svm_path/certify"):
             theta2, delta2, gap = gap_theta_delta(
-                X, y, w2, b2, lam, None, n_feas_iters=n_feas_iters, col=col,
+                X, y, w2, b2, lam, sm, n_feas_iters=n_feas_iters, col=col,
                 u=u_fin,
             )
 
@@ -727,10 +742,9 @@ def _engine_jit(static_kw: tuple, batched: Optional[str] = None):
 
     ``batched``: None (single path), ``"grids"`` (shared problem, batched
     lambda grids — X/y/anchors broadcast by vmap, not materialized), or
-    ``"problems"`` (independent problems, everything batched). The anchor
-    carry (``w0/b0/theta0/delta0``) is donated in the single-path engine so
-    XLA may alias it straight into the scan carry — skipped on backends
-    without donation support (CPU) to avoid spurious warnings.
+    ``"problems"`` (independent problems, everything batched). Nothing is
+    donated: no output has an anchor's shape (they are stacked per step),
+    so the TPU compiler could use none of the anchor buffers.
     ``"grids_compact"``/``"problems_compact"`` route to the scan-outer /
     vmap-inner :func:`_batched_path_scan_program` (shared-cap compaction —
     the plain vmapped program would run every switch branch); note the extra
@@ -750,22 +764,17 @@ def _engine_jit(static_kw: tuple, batched: Optional[str] = None):
         raw = partial(_batched_path_scan_program,
                       shared_x=(batched == "grids_compact"),
                       **dict(static_kw))
-        fn = jax.jit(raw)
-        _ENGINE_CACHE[key] = fn
-        return fn
-    raw = partial(_path_scan_program, **dict(static_kw))
-    # arg order: (X, y, lambdas, w0, b0, theta0, delta0, lam0, L, tau, tol)
-    if batched == "grids":
-        raw = jax.vmap(raw, in_axes=(None, None, 0, None, None, None, None,
-                                     None, None, None, None))
-    elif batched == "problems":
-        raw = jax.vmap(raw, in_axes=(0, 0, 0, 0, 0, 0, None, 0, None, None,
-                                     None))
-    donate = ()
-    if batched is None and jax.default_backend() != "cpu":
-        donate = (3, 4, 5, 6)
-    fn = jax.jit(raw, donate_argnums=donate)
-    _ENGINE_CACHE[key] = fn
+    else:
+        raw = partial(_path_scan_program, **dict(static_kw))
+        # arg order: (X, y, lambdas, w0, b0, theta0, delta0, lam0, L, tau,
+        # tol)
+        if batched == "grids":
+            raw = jax.vmap(raw, in_axes=(None, None, 0, None, None, None,
+                                         None, None, None, None, None))
+        elif batched == "problems":
+            raw = jax.vmap(raw, in_axes=(0, 0, 0, 0, 0, 0, None, 0, None,
+                                         None, None))
+    fn = _ENGINE_CACHE[key] = jax.jit(raw)
     return fn
 
 
@@ -779,13 +788,10 @@ def engine_cache_info() -> dict:
     variant built by :func:`_engine_jit`, with ``n_traces`` the number of
     distinct traces jit holds for it (one per argument-shape signature; a
     same-config same-shape call that bumps this number is a retrace
-    regression). ``-1`` when the running jax has no ``_cache_size`` probe.
+    regression).
     """
-    info = {}
-    for (static_kw, batched), fn in _ENGINE_CACHE.items():
-        probe = getattr(fn, "_cache_size", None)
-        info[(batched, static_kw)] = int(probe()) if probe else -1
-    return info
+    return {(batched, static_kw): fn._cache_size()
+            for (static_kw, batched), fn in _ENGINE_CACHE.items()}
 
 
 def _validate_reduce(reduce: str) -> str:
@@ -950,6 +956,10 @@ def svm_path_scan(
     static_kw = _static_opts(max_iters, screening, dynamic, screen_every,
                              use_pallas, exact_lipschitz, reduce, rules,
                              guards)
+    if dict(static_kw)["use_pallas"]:
+        from repro.kernels.ops import place_row_major  # lazy: no cycle
+
+        X = place_row_major(X)
     engine = _engine_jit(static_kw, batched=None)
     t0 = time.perf_counter()
     outs = engine(X, y, jnp.asarray(lambdas, X.dtype), w0, b0, theta0,
@@ -1002,14 +1012,16 @@ def svm_path_scan_sharded(
     the un-psummed full margins); the dynamic in-solver re-screen is not
     yet plumbed through the collectives seam.
 
-    For an ``X`` too large for any single device, pass ``X``/``y`` already
-    placed on the mesh (``jax.device_put`` with a ``NamedSharding`` matching
-    the in-specs): the setup reductions here (``lambda_max``, anchors) then
-    run SPMD on the sharded global array instead of materializing ``X`` on
-    device 0.
+    ``X``/``y`` go straight from the host (or from their current
+    placement) to their shards on the mesh, so ``X`` is never materialized
+    whole on one device, and the setup reductions (``lambda_max``, anchors)
+    run SPMD on the sharded global array. Shapes that the mesh does not
+    divide are zero-padded: padded feature rows screen out and are sliced
+    off the result, padded samples are masked out of the loss, the
+    certificate and the anchor (``_path_scan_program``'s ``sm``).
     """
-    from .distributed import mesh_collectives, shard_map  # lazy: no cycle
-    from jax.sharding import PartitionSpec as P
+    from .distributed import mesh_collectives  # lazy: no cycle
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     if dynamic:
         # validate at dispatch — previously this only surfaced as a
@@ -1022,32 +1034,49 @@ def svm_path_scan_sharded(
             "engine (svm_path(engine='host', dynamic=True))."
         )
 
-    X = jnp.asarray(X)
-    y = jnp.asarray(y)
-    m, n = X.shape
+    m, n = np.shape(X)
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    d_size = int(np.prod([sizes[a] for a in data_axes]))
+    m_pad = -(-m // sizes["model"]) * sizes["model"]
+    n_pad = -(-n // d_size) * d_size
 
-    lam_max_val = float(lambda_max(X, y))
+    def place(a, spec, shape):
+        pads = [(0, t - s) for s, t in zip(np.shape(a), shape)]
+        if any(p for _, p in pads):
+            a = (jnp.pad(a, pads) if isinstance(a, jax.Array)
+                 else np.pad(np.asarray(a), pads))
+        return jax.device_put(a, NamedSharding(mesh, spec))
+
+    X = place(X, P("model", *data_axes), (m_pad, n_pad))
+    y = place(y, P(*data_axes), (n_pad,))
+    sm = (None if n_pad == n else
+          place(np.ones((n,), X.dtype), P(*data_axes), (n_pad,)))
+
+    lam_max_val = float(lambda_max(X, y, sm))
     if lambdas is None:
         lambdas = default_lambda_grid(lam_max_val, n_lambdas, lam_min_ratio)
     lambdas = _validate_grid(lambdas)
 
-    w0 = jnp.zeros((m,), X.dtype)
-    b0 = bias_at_lambda_max(y)
-    theta0 = theta_at_lambda_max(y, jnp.asarray(lam_max_val, X.dtype))
+    w0 = jnp.zeros((m_pad,), X.dtype)
+    b0 = bias_at_lambda_max(y, sm)
+    theta0 = theta_at_lambda_max(y, jnp.asarray(lam_max_val, X.dtype), sm)
     delta0 = jnp.asarray(0.0, X.dtype)
 
     static_kw = _static_opts(max_iters, screening, False, 1, False,
                              exact_lipschitz, "mask", rules, guards)
     col = mesh_collectives(mesh, data_axes)
 
-    def local_fn(Xb, yb, lams, w0b, b0b, th0b, d0b, lam0b, taub, tolb):
+    def local_fn(Xb, yb, lams, w0b, b0b, th0b, d0b, lam0b, taub, tolb,
+                 *smb):
         return _path_scan_program(
             Xb, yb, lams, w0b, b0b, th0b, d0b, lam0b, None, taub, tolb,
-            col=col, **dict(static_kw),
+            col=col, sm=smb[0] if smb else None, **dict(static_kw),
         )
 
     in_specs = (P("model", *data_axes), P(*data_axes), P(), P("model"), P(),
                 P(*data_axes), P(), P(), P(), P())
+    if sm is not None:
+        in_specs += (P(*data_axes),)
     out_specs = ScanPathOutputs(
         w=P(None, "model"), b=P(), obj=P(), kept=P(), active=P(),
         n_iters=P(), converged=P(), gap=P(), delta=P(),
@@ -1056,17 +1085,22 @@ def svm_path_scan_sharded(
         # inside the body (solver._make_fista_body), so shards agree
         health=P(),
     )
-    fn = jax.jit(shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False))
+    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False))
     t0 = time.perf_counter()
     outs = fn(X, y, jnp.asarray(lambdas, X.dtype), w0, b0, theta0, delta0,
               jnp.asarray(lam_max_val, X.dtype),
               jnp.asarray(float(tau), X.dtype),
-              jnp.asarray(float(tol), X.dtype))
+              jnp.asarray(float(tol), X.dtype),
+              *(() if sm is None else (sm,)))
     outs = jax.block_until_ready(outs)
     t1 = time.perf_counter()
     wall_s = t1 - t0
     obs_trace.complete("scan_sharded.dispatch", t0, t1, steps=len(lambdas))
+    if m_pad != m:
+        fmask = outs.fmask[:, :m]
+        outs = outs._replace(w=outs.w[:, :m], fmask=fmask, kept=jnp.sum(
+            fmask, axis=1).astype(jnp.int32))
     r = _to_path_result(lambdas, outs, lam_max_val, wall_s, screening,
                         static_kw, engine="scan_sharded")
     r.extras["engine"] = "scan_sharded"
@@ -1136,6 +1170,10 @@ def svm_path_batched(
                              use_pallas, exact_lipschitz, reduce, rules,
                              guards)
     compact = dict(static_kw)["reduce"] == "compact"
+    if dict(static_kw)["use_pallas"]:
+        from repro.kernels.ops import place_row_major  # lazy: no cycle
+
+        X = place_row_major(X)
     if X.ndim == 2:
         # one problem, B grids — X/y/anchors stay unbatched (vmap broadcasts)
         if lambdas is None:

@@ -31,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..linalg import dot
 from ..screening import (
     SAFE_TAU,
     FeatureReductions,
@@ -73,15 +74,16 @@ class DVIRule(FeatureVIRule):
             lam0, theta0, delta0 = anchor
             sh0 = shared_scalars(y, jnp.asarray(lam0), jnp.asarray(region.lam2),
                                  theta0, delta=delta0)
-            d_theta0 = X @ (y * theta0)
+            d_theta0 = dot(X, y * theta0)
             if self._static is not None:
                 d_one, d_y, d_sq = self._static
                 red0 = FeatureReductions(d_theta=d_theta0, d_one=d_one,
                                          d_y=d_y, d_sq=d_sq)
             else:
                 ones = jnp.ones((X.shape[1],), X.dtype)
-                red0 = FeatureReductions(d_theta=d_theta0, d_one=X @ y,
-                                         d_y=X @ ones, d_sq=jnp.sum(X * X, axis=1))
+                red0 = FeatureReductions(
+                    d_theta=d_theta0, d_one=dot(X, y), d_y=dot(X, ones),
+                    d_sq=jnp.sum(X * X, axis=1))
             b = jnp.minimum(b, screen_bounds_from_reductions(red0, sh0))
         self._anchor = (region.lam1, region.theta1, region.delta)
         return b

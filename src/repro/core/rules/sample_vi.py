@@ -62,6 +62,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..linalg import dot
 from ..screening import _EPS, _t_max
 from .base import AXIS_SAMPLES, ConvexRegion, ScreeningRule, register_rule
 
@@ -162,7 +163,7 @@ def sample_margin_surplus(
         # in double precision end to end
         u1 = jnp.full(y.shape, region.b1, jnp.result_type(X.dtype, y.dtype))
     else:
-        u1 = X.T @ region.w1 + region.b1
+        u1 = dot(X.T, region.w1) + region.b1
     if x_sq is None:
         x_sq = jnp.sum(X * X, axis=0)
     surplus = margin_surplus_core(
@@ -213,5 +214,5 @@ class SampleVIRule(ScreeningRule):
 
     def verify(self, X, y, w, b, screened_idx) -> jax.Array:
         """Screened samples whose margin at ``(w, b)`` is actually < 1."""
-        u = X[:, screened_idx].T @ w + b
+        u = dot(X[:, screened_idx].T, w) + b
         return violators_from_margins(y, u, screened_idx)

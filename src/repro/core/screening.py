@@ -61,6 +61,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .linalg import dot
+
 __all__ = [
     "FeatureReductions",
     "ScreenShared",
@@ -169,7 +171,7 @@ def d_theta_sparse(X: jax.Array, y: jax.Array, theta1: jax.Array,
     support = min(support, theta1.shape[0])
     vals, idx = jax.lax.top_k(jnp.abs(theta1), support)
     coef = (y * theta1)[idx]                       # signed, true values
-    return X[:, idx] @ coef
+    return dot(X[:, idx], coef)
 
 
 def shared_scalars(
@@ -196,8 +198,8 @@ def shared_scalars(
         jnp.asarray(lam2, dtype),
         one_y=jnp.sum(y),
         theta_dot_one=jnp.sum(theta1),
-        theta_dot_y=theta1 @ y,
-        theta_sq=theta1 @ theta1,
+        theta_dot_y=dot(theta1, y),
+        theta_sq=dot(theta1, theta1),
         n_tot=jnp.asarray(float(n), dtype),  # ||y||^2 = n for +-1 labels
         delta=jnp.asarray(delta, dtype),
     )
@@ -378,8 +380,8 @@ def anchor_stats(y: jax.Array, lam, theta1: jax.Array, delta,
         lam=jnp.asarray(lam, dtype),
         delta=jnp.asarray(delta, dtype),
         theta_dot_one=jnp.sum(theta1),
-        theta_dot_y=theta1 @ y,
-        theta_sq=theta1 @ theta1,
+        theta_dot_y=dot(theta1, y),
+        theta_sq=dot(theta1, theta1),
         d_theta=d_theta,
     )
 

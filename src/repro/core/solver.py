@@ -82,6 +82,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .linalg import dot
 from .screening import (
     SAFE_TAU,
     FeatureReductions,
@@ -251,7 +252,8 @@ def _rel3(s: "FistaState") -> jax.Array:
 
 
 def lipschitz_estimate(X: jax.Array, n_iters: int = 30, key: Optional[jax.Array] = None,
-                       col: Collectives = LOCAL) -> jax.Array:
+                       col: Collectives = LOCAL,
+                       sample_mask: Optional[jax.Array] = None) -> jax.Array:
     """Power iteration for ``sigma_max([X; 1^T])^2`` (augmented bias row).
 
     Monotonicity along a path: any row/column submatrix of ``[X; 1^T]`` that
@@ -263,21 +265,23 @@ def lipschitz_estimate(X: jax.Array, n_iters: int = 30, key: Optional[jax.Array]
     ``col`` binds the two GEMV reductions to mesh collectives when ``X`` is a
     ``shard_map`` block (under sharding every data shard seeds the same local
     key, so the implied global start vector is block-periodic — any nonzero
-    start is valid for power iteration).
+    start is valid for power iteration). ``sample_mask`` (0/1 over samples)
+    restricts the bias row to the live samples of a zero-padded ``X``.
     """
     n = X.shape[1]
     if key is None:
         key = jax.random.PRNGKey(0)
     v = jax.random.normal(key, (n,), dtype=X.dtype)
+    bias_row = 1.0 if sample_mask is None else sample_mask
 
     def norm(v):
         return jnp.sqrt(jnp.maximum(col.psum_data(jnp.sum(v * v)), 0.0))
 
     def body(v, _):
         v = v / jnp.maximum(norm(v), 1e-30)
-        u_w = col.psum_data(X @ v)
-        u_b = col.psum_data(jnp.sum(v))
-        v = col.psum_model(X.T @ u_w) + u_b
+        u_w = col.psum_data(dot(X, v))
+        u_b = col.psum_data(jnp.sum(v * bias_row))
+        v = col.psum_model(dot(X.T, u_w)) + u_b * bias_row
         return v, None
 
     v, _ = jax.lax.scan(body, v, None, length=n_iters)
@@ -285,7 +289,7 @@ def lipschitz_estimate(X: jax.Array, n_iters: int = 30, key: Optional[jax.Array]
 
 
 def _objective(X, y, w, b, lam, sample_mask=None):
-    xi = jnp.maximum(0.0, 1.0 - y * (X.T @ w + b))
+    xi = jnp.maximum(0.0, 1.0 - y * (dot(X.T, w) + b))
     if sample_mask is not None:
         xi = xi * sample_mask
     return 0.5 * jnp.sum(xi * xi) + lam * jnp.sum(jnp.abs(w))
@@ -312,7 +316,7 @@ def _margin_obj_sweep(X, y, lam, w, b, sm, use_pallas, col=LOCAL, valid_m=None):
             loss = 0.5 * jnp.sum(xi * xi)
         loss = jnp.asarray(loss, X.dtype)
     else:
-        u = col.psum_model(X.T @ w)
+        u = col.psum_model(dot(X.T, w))
         xi = jnp.maximum(0.0, 1.0 - y * (u + b))
         if sm is not None:
             xi = xi * sm
@@ -321,12 +325,25 @@ def _margin_obj_sweep(X, y, lam, w, b, sm, use_pallas, col=LOCAL, valid_m=None):
 
 
 def _grad_sweep(X, y, xi, use_pallas, col=LOCAL, valid_m=None):
-    """``grad_w = -X (y * xi)`` — the transposed pass over X."""
+    """``grad_w = -X (y * xi)`` — the transposed pass over X (one entry per
+    row of ``X``: a padded kernel operand yields padded rows, which the
+    caller slices off)."""
     if use_pallas and col is LOCAL:
         from repro.kernels.ops import hinge_grad_op  # lazy: no import cycle
 
         return hinge_grad_op(X, y, xi, valid_m=valid_m).astype(X.dtype)
-    return col.psum_data(-(X @ (y * xi)))
+    return col.psum_data(-dot(X, y * xi))
+
+
+def _sweep_operand(X, use_pallas, col=LOCAL):
+    """The matrix the FISTA sweeps read: on the Pallas route, ``X`` padded
+    once to the kernels' block multiples (``kernels/ops.kernel_operand``),
+    so no sweep of the solve copies X; otherwise ``X`` itself."""
+    if use_pallas and col is LOCAL:
+        from repro.kernels.ops import kernel_operand  # lazy: no import cycle
+
+        return kernel_operand(X)
+    return X
 
 
 def _init_state(X, y, lam, w0, b0, sm, use_pallas, col=LOCAL,
@@ -391,7 +408,7 @@ def _make_fista_body(X, y, lam, inv_L, sm, fmask=None, use_pallas=False,
         xi = jnp.maximum(0.0, 1.0 - y * (u_a + b_a))
         if sm is not None:
             xi = xi * sm
-        gw = _grad_sweep(X, y, xi, use_pallas, col, valid_m)
+        gw = _grad_sweep(X, y, xi, use_pallas, col, valid_m)[:w_a.shape[0]]
         gb = col.psum_bias(-jnp.sum(y * xi))
         w_new = mask_w(soft_threshold(w_a - inv_Le * gw, lam * inv_Le))
         b_new = b_a - inv_Le * gb
@@ -486,6 +503,7 @@ def fista_run(
     col: Collectives = LOCAL,
     valid_m: Optional[jax.Array] = None,
     guards: bool = False,
+    sweep_X: Optional[jax.Array] = None,
 ) -> FistaResult:
     """The raw (unjitted) FISTA loop — trace-safe building block.
 
@@ -501,9 +519,13 @@ def fista_run(
     enables the numerical health guard (warm-start sanitization, on-device
     rollback with step-size backoff, trip-bounded loop — see
     :func:`_make_fista_body`); the trip count is returned as
-    ``FistaResult.health``.
+    ``FistaResult.health``. ``sweep_X`` is the caller's already padded
+    kernel operand of ``X`` (a path engine pads once per path); by default
+    the solve pads its own.
     """
-    init = _init_state(X, y, lam, w0, jnp.asarray(b0, X.dtype), sample_mask,
+    Xs = sweep_X if sweep_X is not None else _sweep_operand(X, use_pallas,
+                                                             col)
+    init = _init_state(Xs, y, lam, w0, jnp.asarray(b0, X.dtype), sample_mask,
                        use_pallas, col, valid_m, guards=guards)
 
     def cond(s: FistaState):
@@ -513,7 +535,7 @@ def fista_run(
             go = go & (s.health < MAX_GUARD_TRIPS)
         return go
 
-    body = _make_fista_body(X, y, lam, inv_L, sample_mask, feature_mask,
+    body = _make_fista_body(Xs, y, lam, inv_L, sample_mask, feature_mask,
                             use_pallas, col, valid_m, guards=guards)
     out = jax.lax.while_loop(cond, body, init)
     return FistaResult(
@@ -622,7 +644,7 @@ def gap_theta_delta(
     """
     sm = sample_mask
     if u is None:
-        u = col.psum_model(X.T @ w)
+        u = col.psum_model(dot(X.T, w))
     xi = jnp.maximum(0.0, 1.0 - y * (u + b))
     if sm is not None:
         xi = xi * sm
@@ -635,13 +657,14 @@ def gap_theta_delta(
         n_eff = col.psum_data(jnp.asarray(float(y.shape[0]), X.dtype))
 
     def corr_scale(alpha):
-        corr = col.psum_data(X @ (y * alpha))  # fhat_j^T alpha for all j
+        corr = col.psum_data(dot(X, y * alpha))  # fhat_j^T alpha for all j
         mx = col.pmax_model(jnp.max(jnp.abs(corr)))
         return jnp.minimum(1.0, lam / jnp.maximum(mx, 1e-30))
 
     def body(alpha, _):
         alpha = alpha * corr_scale(alpha)
-        alpha = jnp.maximum(0.0, alpha - col.psum_data(alpha @ y) / n_eff * y)
+        alpha = jnp.maximum(
+            0.0, alpha - col.psum_data(dot(alpha, y)) / n_eff * y)
         if sm is not None:
             alpha = alpha * sm
         return alpha, None
@@ -656,7 +679,7 @@ def gap_theta_delta(
     # ulps of p_obj so cancellation noise can never *under*-inflate delta
     # (an underestimated delta is the unsafe direction)
     gap = jnp.maximum(gap, 4.0 * jnp.finfo(X.dtype).eps * jnp.abs(p_obj))
-    eq_resid = jnp.abs(col.psum_data(alpha @ y)) / jnp.sqrt(n_eff)
+    eq_resid = jnp.abs(col.psum_data(dot(alpha, y))) / jnp.sqrt(n_eff)
     delta = (jnp.sqrt(2.0 * gap) + 2.0 * eq_resid) / lam
     theta = alpha / lam
     # fail-safe: a non-finite certificate must never feed screening. A NaN
@@ -693,6 +716,7 @@ def _dynamic_run(
     sample_shrink: float = 2.0,
     sample_floor: float = 1e-3,
     guards: bool = False,
+    sweep_X: Optional[jax.Array] = None,
 ) -> DynamicFistaResult:
     """Raw segmented dynamic solve (see :func:`fista_solve_dynamic`).
 
@@ -720,7 +744,7 @@ def _dynamic_run(
 
     def bound_statics(smv):
         """theta-independent bound reductions over the live samples."""
-        return (X @ (y * smv), X @ smv, (X * X) @ smv,
+        return (dot(X, y * smv), dot(X, smv), dot(X * X, smv),
                 jnp.sum(y * smv), jnp.sum(smv))
 
     # one sweep hoisted out of the loop; with dynamic_samples the values are
@@ -728,7 +752,8 @@ def _dynamic_run(
     # samples (the sm_dirty flag) — a stabilized sample mask costs nothing
     statics0 = bound_statics(sm_vec)
 
-    s0 = _init_state(X, y, lam, w0, jnp.asarray(b0, X.dtype), sm, use_pallas,
+    Xs = sweep_X if sweep_X is not None else _sweep_operand(X, use_pallas)
+    s0 = _init_state(Xs, y, lam, w0, jnp.asarray(b0, X.dtype), sm, use_pallas,
                      valid_m=valid_m, guards=guards)
     kept0 = jnp.full((n_seg,), -1, jnp.int32)
     gaps0 = jnp.full((n_seg,), jnp.inf, X.dtype)
@@ -751,7 +776,7 @@ def _dynamic_run(
         seg_sm = smask if dynamic_samples else sm
 
         # -- segment: up to screen_every FISTA steps on the live mask ------
-        body = _make_fista_body(X, y, lam, inv_L, seg_sm, fmask, use_pallas,
+        body = _make_fista_body(Xs, y, lam, inv_L, seg_sm, fmask, use_pallas,
                                 valid_m=valid_m, guards=guards)
         k_stop = jnp.minimum(s.k + screen_every, max_iters)
 
@@ -780,11 +805,11 @@ def _dynamic_run(
         d_one_c, d_y_c, d_sq_c, one_y_c, n_tot_c = statics
         sh = shared_scalars_from_stats(
             lam, lam, one_y=one_y_c,
-            theta_dot_one=jnp.sum(theta), theta_dot_y=theta @ y,
-            theta_sq=theta @ theta, n_tot=n_tot_c, delta=delta,
+            theta_dot_one=jnp.sum(theta), theta_dot_y=dot(theta, y),
+            theta_sq=dot(theta, theta), n_tot=n_tot_c, delta=delta,
         )
         red = FeatureReductions(
-            d_theta=X @ (y * theta), d_one=d_one_c, d_y=d_y_c, d_sq=d_sq_c
+            d_theta=dot(X, y * theta), d_one=d_one_c, d_y=d_y_c, d_sq=d_sq_c
         )
         # two independent certificates, elementwise min (each is a valid
         # upper bound on |fhat_j^T theta*|): the at-lambda VI cap, and the
@@ -832,7 +857,7 @@ def _dynamic_run(
         if dynamic_samples:
             changed = changed | (jnp.sum(smask - new_sm) > 0.0)
         u_m, obj_m = _margin_obj_sweep(
-            X, y, lam, w_m, s.b, new_sm if dynamic_samples else sm,
+            Xs, y, lam, w_m, s.b, new_sm if dynamic_samples else sm,
             use_pallas, valid_m=valid_m)
         s_masked = FistaState(
             w=w_m, b=s.b, w_prev=w_m, b_prev=s.b, u=u_m, u_prev=u_m,

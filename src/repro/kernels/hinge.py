@@ -11,16 +11,25 @@ Two tiled GEMV-shaped kernels (the FISTA iteration's only O(mn) work):
                         linearly from carried ``u`` instead of re-sweeping X).
   * ``hinge_grad``    : g = -X (y * xi), the transposed sweep.
 
-Both accumulate in fp32 VMEM scratch regardless of input dtype; tiles are
-(8k-aligned sublane x 128-aligned lane) blocks.
+Layout (what Mosaic accepts): X tiles are (sublane x 128-lane) blocks;
+every vector operand and result is a lane-dense ``(1, len)`` row, so its
+blocks are ``(1, block)`` with ``block`` a multiple of 128, and the loss
+partials are one 128-lane row per output block (the partial in lane 0).
+The scalars (live-row count, bias) are scalar-prefetch operands in SMEM.
+Both sweeps are MXU contractions at ``Precision.HIGHEST`` with fp32
+accumulation in VMEM scratch: the carried margins feed the gap
+certificate, so they must be float32-accurate on the chip, not bf16-pass
+products.
 
 Row-validity counts (the active-set compaction seam, ``core/path_scan.py``
 ``reduce="compact"``): both kernels take a dynamic scalar ``valid_m`` — the
-number of live leading feature rows. Compacted operands zero-pad the rows
-past ``valid_m``, so those blocks contribute nothing; the count lets the
-kernel *skip* their MXU work outright (``pl.when`` on the feature-block id)
-instead of multiplying zeros. Passing ``valid_m = m`` is the full-matrix
-case and leaves the schedule untouched.
+number of live leading feature rows — as a scalar-prefetch operand.
+Compacted operands zero-pad the rows past ``valid_m``, so those blocks
+contribute nothing; the count lets the kernel *skip* their MXU work
+(``pl.when`` on the feature-block id), and the index maps clamp the block
+index of skipped steps to the last live block, so their X tiles are not
+fetched from HBM either. Passing ``valid_m = m`` is the full-matrix case and
+leaves the schedule untouched.
 """
 
 from __future__ import annotations
@@ -32,9 +41,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HI = jax.lax.Precision.HIGHEST
+_NN = (((1,), (0,)), ((), ()))  # (1, k) x (k, n): contract lhs lanes
+_NT = (((1,), (1,)), ((), ()))  # (1, k) x (n, k): contract both lane axes
 
-def _margin_kernel(x_ref, w_ref, y_ref, b_ref, vm_ref, u_ref, xi_ref, loss_ref,
-                   acc_ref, *, m_steps):
+
+def _last_live_block(vm_ref, block: int):
+    """Index of the last block holding a live row (0 when none is live)."""
+    return jnp.maximum((vm_ref[0] + block - 1) // block - 1, 0)
+
+
+def _margin_kernel(vm_ref, b_ref, x_ref, w_ref, y_ref, u_ref, xi_ref,
+                   loss_ref, acc_ref):
     j = pl.program_id(1)  # feature-axis reduction step
 
     @pl.when(j == 0)
@@ -46,18 +64,19 @@ def _margin_kernel(x_ref, w_ref, y_ref, b_ref, vm_ref, u_ref, xi_ref, loss_ref,
     @pl.when(j * x_ref.shape[0] < vm_ref[0])
     def _acc():
         x = x_ref[...].astype(jnp.float32)   # (bm, bn)
-        w = w_ref[...].astype(jnp.float32)   # (bm,)
-        acc_ref[...] += w @ x                # (bn,) partial of X^T w
+        w = w_ref[...].astype(jnp.float32)   # (1, bm)
+        acc_ref[...] += jax.lax.dot_general(
+            w, x, _NN, precision=_HI, preferred_element_type=jnp.float32)
 
-    @pl.when(j == m_steps - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _fin():
         y = y_ref[...].astype(jnp.float32)
-        b = b_ref[0]
         u = acc_ref[...]
-        xi = jnp.maximum(0.0, 1.0 - y * (u + b))
+        xi = jnp.maximum(0.0, 1.0 - y * (u + b_ref[0]))
         u_ref[...] = u
         xi_ref[...] = xi
-        loss_ref[0] = 0.5 * jnp.sum(xi * xi)
+        lane = jax.lax.broadcasted_iota(jnp.int32, loss_ref.shape, 1)
+        loss_ref[...] = jnp.where(lane == 0, 0.5 * jnp.sum(xi * xi), 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))
@@ -76,37 +95,43 @@ def hinge_margin_pallas(
     m, n = X.shape
     assert m % block_m == 0 and n % block_n == 0
     grid = (n // block_n, m // block_m)
-    b_vec = jnp.full((8,), b, jnp.float32)
-    vm_vec = jnp.full((8,), m if valid_m is None else valid_m, jnp.int32)
+    vm = jnp.reshape(jnp.asarray(m if valid_m is None else valid_m,
+                                 jnp.int32), (1,))
+    b_s = jnp.reshape(jnp.asarray(b, jnp.float32), (1,))
 
-    kernel = functools.partial(_margin_kernel, m_steps=grid[1])
+    def feat_block(j, vm_ref):
+        return jnp.minimum(j, _last_live_block(vm_ref, block_m))
+
     u, xi, loss_parts = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_n), lambda i, j: (j, i)),
-            pl.BlockSpec((block_m,), lambda i, j: (j,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((8,), lambda i, j: (0,)),
-            pl.BlockSpec((8,), lambda i, j: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-        ],
+        _margin_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_m, block_n),
+                             lambda i, j, vm, b: (feat_block(j, vm), i)),
+                pl.BlockSpec((1, block_m),
+                             lambda i, j, vm, b: (0, feat_block(j, vm))),
+                pl.BlockSpec((1, block_n), lambda i, j, vm, b: (0, i)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_n), lambda i, j, vm, b: (0, i)),
+                pl.BlockSpec((1, block_n), lambda i, j, vm, b: (0, i)),
+                pl.BlockSpec((1, 128), lambda i, j, vm, b: (0, i)),
+            ],
+            scratch_shapes=[pltpu.VMEM((1, block_n), jnp.float32)],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32),
+            jax.ShapeDtypeStruct((1, grid[0] * 128), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_n,), jnp.float32)],
         interpret=interpret,
-    )(X, w, y, b_vec, vm_vec)
-    return u, xi, jnp.sum(loss_parts)
+    )(vm, b_s, X, w.reshape(1, m), y.reshape(1, n))
+    return u[0], xi[0], jnp.sum(loss_parts)
 
 
-def _grad_kernel(x_ref, v_ref, vm_ref, g_ref, acc_ref, *, n_steps):
+def _grad_kernel(vm_ref, x_ref, v_ref, g_ref, acc_ref):
     i = pl.program_id(0)  # feature-axis output block
     j = pl.program_id(1)  # sample-axis reduction step
 
@@ -119,10 +144,11 @@ def _grad_kernel(x_ref, v_ref, vm_ref, g_ref, acc_ref, *, n_steps):
     @pl.when(i * x_ref.shape[0] < vm_ref[0])
     def _acc():
         x = x_ref[...].astype(jnp.float32)   # (bm, bn)
-        v = v_ref[...].astype(jnp.float32)   # (bn,) = y * xi
-        acc_ref[...] += x @ v
+        v = v_ref[...].astype(jnp.float32)   # (1, bn) = y * xi
+        acc_ref[...] += jax.lax.dot_general(
+            v, x, _NT, precision=_HI, preferred_element_type=jnp.float32)
 
-    @pl.when(j == n_steps - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _fin():
         g_ref[...] = -acc_ref[...]
 
@@ -141,18 +167,30 @@ def hinge_grad_pallas(
     m, n = X.shape
     assert m % block_m == 0 and n % block_n == 0
     grid = (m // block_m, n // block_n)
-    vm_vec = jnp.full((8,), m if valid_m is None else valid_m, jnp.int32)
-    kernel = functools.partial(_grad_kernel, n_steps=grid[1])
+    vm = jnp.reshape(jnp.asarray(m if valid_m is None else valid_m,
+                                 jnp.int32), (1,))
+
+    def tile(i, j, vm_ref):
+        # a skipped output block re-points at the tile fetched last (the
+        # final tile of the last live block), so the pipeline fetches nothing
+        last = _last_live_block(vm_ref, block_m)
+        skip = i > last
+        return (jnp.where(skip, last, i),
+                jnp.where(skip, grid[1] - 1, j))
+
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
-            pl.BlockSpec((8,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_m,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_m,), jnp.float32)],
+        _grad_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_m, block_n), tile),
+                pl.BlockSpec((1, block_n),
+                             lambda i, j, vm: (0, tile(i, j, vm)[1])),
+            ],
+            out_specs=pl.BlockSpec((1, block_m), lambda i, j, vm: (0, i)),
+            scratch_shapes=[pltpu.VMEM((1, block_m), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
         interpret=interpret,
-    )(X, v, vm_vec)
+    )(vm, X, v.reshape(1, n))[0]

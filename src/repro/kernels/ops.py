@@ -12,6 +12,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.screening import shared_scalars
 from . import hinge as _hinge
@@ -64,6 +65,42 @@ def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
     return jnp.pad(x, pads)
 
 
+def _pad_len(v: jax.Array, size: int) -> jax.Array:
+    """Zero-pad a vector to ``size`` entries (no-op when already there)."""
+    return v if v.shape[0] == size else jnp.pad(v, (0, size - v.shape[0]))
+
+
+def kernel_operand(X: jax.Array, block_m: int = 256,
+                   block_n: int = 512) -> jax.Array:
+    """``X`` zero-padded to the sweep kernels' block multiples.
+
+    The scan engine pads once per path, a standalone solve once per solve,
+    and every :func:`margin_obj_op` / :func:`hinge_grad_op` call then gets
+    the padded operand (whose own padding is a no-op), so no sweep copies X.
+    """
+    return _pad_to(_pad_to(X, block_m, 0), block_n, 1)
+
+
+def place_row_major(X):
+    """``X`` on the device in row-major tiled layout, on a TPU.
+
+    The default device layout of an (m, n) array whose n is not a multiple
+    of 128 can be column-major; a program that hands it to the kernels then
+    keeps a row-major copy of all of X. Path engines that run the Pallas
+    sweeps place X with this once, before dispatch (off a TPU: unchanged).
+    """
+    if not _on_tpu():
+        return X
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    sharding = getattr(X, "sharding", None)
+    if sharding is None or len(sharding.device_set) != 1:
+        sharding = SingleDeviceSharding(jax.devices()[0])
+    fmt = Format(Layout(major_to_minor=tuple(range(np.ndim(X)))), sharding)
+    return jax.device_put(X, fmt)
+
+
 def screen_bounds_op(
     X: jax.Array,
     y: jax.Array,
@@ -87,14 +124,14 @@ def screen_bounds_op(
     m, n = X.shape
     yf = y.astype(jnp.float32)
     tf = theta1.astype(jnp.float32)
-    rhs = jnp.stack([yf * tf, yf, jnp.ones_like(yf), jnp.zeros_like(yf)], axis=1)
+    side = _screen.stack_rows(yf * tf, yf, jnp.ones_like(yf))
     sh = shared_scalars(yf, lam1, lam2, tf, delta=delta)
     scalars = _screen.pack_shared(sh)
 
-    Xp = _pad_to(_pad_to(X, block_m, 0), block_n, 1)
-    rhs_p = _pad_to(rhs, block_n, 0)
+    Xp = kernel_operand(X, block_m, block_n)
     out = _screen.screen_bounds_pallas(
-        Xp, rhs_p, scalars, block_m=block_m, block_n=block_n, interpret=interpret
+        Xp, _pad_to(side, block_n, 1), scalars,
+        block_m=block_m, block_n=block_n, interpret=interpret,
     )
     return out[:m]
 
@@ -122,23 +159,20 @@ def sample_surplus_op(
     if interpret is None:
         interpret = _default_interpret()
     m, n = X.shape
-    wf = w.astype(jnp.float32)
-    lhs = jnp.stack(
-        [wf, jnp.zeros_like(wf), jnp.zeros_like(wf), jnp.zeros_like(wf)], axis=1
-    )
     yf = y.astype(jnp.float32)
     has_history = u_prev is not None
     up = (u_prev.astype(jnp.float32) if has_history else jnp.zeros_like(yf))
-    aux = jnp.stack([yf, up], axis=1)
     scalars = _screen.pack_sample_scalars(
         b, dw, db, shrink_factor, margin_floor, has_history
     )
 
-    Xp = _pad_to(_pad_to(X, block_m, 0), block_n, 1)
-    lhs_p = _pad_to(lhs, block_m, 0)   # zero rows: no u / ||x||^2 contribution
-    aux_p = _pad_to(aux, block_n, 0)   # y=0 columns are sliced off below
+    Xp = kernel_operand(X, block_m, block_n)
+    # zero w columns: no u / ||x||^2 contribution; padded samples (y = 0)
+    # are sliced off below
+    side = _pad_to(_screen.stack_rows(w), block_m, 1)
+    aux = _pad_to(_screen.stack_rows(yf, up), block_n, 1)
     out = _screen.screen_bounds_pallas(
-        Xp, lhs_p, scalars, aux=aux_p, axis="samples",
+        Xp, side, scalars, aux=aux, axis="samples",
         block_m=block_m, block_n=block_n, interpret=interpret,
     )
     return out[:n]
@@ -157,19 +191,21 @@ def margin_obj_op(
     each *new* iterate, so the objective costs no extra pass over X (the
     separate ``_objective`` sweep of the pre-fusion solver is gone).
 
-    ``valid_m`` (dynamic scalar): live leading-row count of a compacted
-    active set (``core/path_scan.py reduce="compact"``); rows past it must
-    be zero padding — the kernel skips their blocks.
+    ``X`` may already be padded (:func:`kernel_operand`): the logical sizes
+    are those of ``w`` and ``y``, and extra rows/columns of ``X`` must be
+    zero. ``valid_m`` (dynamic scalar): live leading-row count of a
+    compacted active set (``core/path_scan.py reduce="compact"``); rows past
+    it must be zero padding — the kernel skips their blocks.
     """
     if interpret is None:
         interpret = _default_interpret()
-    m, n = X.shape
-    Xp = _pad_to(_pad_to(X, block_m, 0), block_n, 1)
-    wp = _pad_to(w, block_m, 0)
+    n = y.shape[0]
+    Xp = kernel_operand(X, block_m, block_n)
+    wp = _pad_len(w, Xp.shape[0])
     # pad y with 0 => padded xi = max(0, 1-0*(u+b)) = 1: inert for u (w rows
     # are zero-padded) but each padded slot adds 0.5 to the loss — mask xi
     # and subtract the padded contribution after the call.
-    yp = _pad_to(y, block_n, 0)
+    yp = _pad_len(y, Xp.shape[1])
     u, xi, loss = _hinge.hinge_margin_pallas(
         Xp, wp, yp, jnp.asarray(b, jnp.float32), valid_m=valid_m,
         block_m=block_m, block_n=block_n, interpret=interpret,
@@ -197,13 +233,17 @@ def hinge_grad_op(
     block_m: int = 256, block_n: int = 512, interpret: bool | None = None,
     valid_m: jax.Array | None = None,
 ) -> jax.Array:
-    """g = -X (y*xi) (kernel-backed). ``valid_m`` as in :func:`margin_obj_op`
-    (output rows past the live count are written as zeros, not computed)."""
+    """g = -X (y*xi) (kernel-backed), one entry per row of ``X``.
+
+    ``X`` may already be padded (:func:`kernel_operand`, extra entries zero;
+    the caller slices ``g`` to its logical rows). ``valid_m`` as in
+    :func:`margin_obj_op` (output rows past the live count are written as
+    zeros, not computed)."""
     if interpret is None:
         interpret = _default_interpret()
-    m, n = X.shape
-    Xp = _pad_to(_pad_to(X, block_m, 0), block_n, 1)
-    v = _pad_to(y.astype(jnp.float32) * xi.astype(jnp.float32), block_n, 0)
+    m = X.shape[0]
+    Xp = kernel_operand(X, block_m, block_n)
+    v = _pad_len(y.astype(jnp.float32) * xi.astype(jnp.float32), Xp.shape[1])
     g = _hinge.hinge_grad_pallas(Xp, v, valid_m=valid_m, block_m=block_m,
                                  block_n=block_n, interpret=interpret)
     return g[:m]

@@ -1,10 +1,10 @@
 """Fused Pallas TPU kernels for screening bounds, parameterized by axis.
 
 One kernel family, two reduction axes, one shared pattern: sweep X once,
-accumulate a (units, 4) reduction block in VMEM across the grid's reduction
-axis, and on the final grid step apply a ~30-flop closed-form finalizer
-entirely in VMEM. X is read from HBM exactly once; nothing of size
-O(units x 4) round-trips to HBM between the reduction and the bound.
+accumulate an (8, units) reduction block in VMEM across the grid's
+reduction axis, and on the final grid step apply a ~30-flop closed-form
+finalizer entirely in VMEM. X is read from HBM exactly once; nothing of
+size O(units x 4) round-trips to HBM between the reduction and the bound.
 
 ``axis="features"`` (paper Alg. 1): per feature row j, reduce over samples
 
@@ -23,16 +23,22 @@ then the margin-surplus finalizer: ``y_i (u_i + b1) - 1 - slack_i`` with
 floor)`` (trust-region and secant slack models; see the rule's docstring).
 
 TPU adaptation notes (vs the paper's per-unit CPU loop):
-  * unit tiles ride the VPU sublanes; reduction tiles ride the 128-wide
-    lanes (feature axis) or MXU contraction (both axes);
-  * the dot-reductions are one (bu, br) x (br, 4) matmul so the MXU does the
-    heavy lifting at fp32 accumulation;
+  * the per-unit side vectors enter as the rows of an (8, len) operand
+    (unused rows zero) and the accumulator is (8, units): units ride the
+    128-wide lanes, so every block Mosaic sees is lane-dense and the
+    result is one ``(1, units)`` row;
+  * the dot-reductions are one (8, br) x (br, bu) MXU contraction, and the
+    squared norms a second one against a ones block — the squared-norm row
+    is merged into the accumulator with a row select, not a scatter;
+  * both contractions run at ``Precision.HIGHEST`` (fp32 on the chip: a
+    bf16-pass reduction could understate a bound and discard a live unit);
+  * the packed scalars live in SMEM;
   * the grid is (units/bu, reduction/br) with the reduction axis innermost
     ("arbitrary" semantics), accumulating into a VMEM scratch block that
     lives across the sweep — the canonical Pallas reduction pattern.
 
 VMEM budget per program instance (defaults bm=256, bn=512, fp32):
-  X tile 512 KiB + side tiles <16 KiB + acc 8 KiB << 16 MiB VMEM.
+  X tile 512 KiB + side tiles 16 KiB + acc 8-16 KiB << 16 MiB VMEM.
 """
 
 from __future__ import annotations
@@ -45,8 +51,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NUM_SCALARS = 12  # packed per-axis scalars, padded to a common length
+SIDE_ROWS = 8     # rows of the stacked side-vector operands (one f32 tile)
 
 _BIG = 1e30  # stands in for inf inside the kernel (avoids 0 * inf = nan)
+_HI = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
@@ -78,6 +86,12 @@ def pack_sample_scalars(b1, dw, db, shrink_factor, margin_floor,
     return jnp.pad(vals, (0, NUM_SCALARS - vals.shape[0]))
 
 
+def stack_rows(*rows: jax.Array) -> jax.Array:
+    """``(SIDE_ROWS, len)`` fp32 operand holding ``rows``, the rest zero."""
+    v = jnp.stack([jnp.asarray(r, jnp.float32) for r in rows])
+    return jnp.pad(v, ((0, SIDE_ROWS - v.shape[0]), (0, 0)))
+
+
 # --------------------------------------------------------------------------
 # closed-form finalizers (vector over the unit tile)
 # --------------------------------------------------------------------------
@@ -102,9 +116,10 @@ def _t_cases(v_ch, qv_qa, qv_sq, r_h, g0, qa_sq, hv):
 
 
 def _feature_bound_from_acc(acc, sc):
-    """VI bound on |fhat^T theta2| from the 4 reductions (vector bm)."""
+    """VI bound on |fhat^T theta2| from the 4 reduction rows of ``acc``
+    ((8, bm)); ``sc`` is the SMEM scalar ref. Returns (1, bm)."""
     eps = jnp.float32(1e-30)
-    d_theta, d_one, d_y, d_sq = acc[:, 0], acc[:, 1], acc[:, 2], acc[:, 3]
+    d_theta, d_one, d_y, d_sq = acc[0:1], acc[1:2], acc[2:3], acc[3:4]
     inv1, inv2 = sc[0], sc[1]
     yc, ysq, r_h_sq, g0 = sc[2], sc[3], sc[4], sc[5]
     qa_sq, a_norm, a_dot_y, hv = sc[6], sc[7], sc[8], sc[9]
@@ -122,9 +137,10 @@ def _feature_bound_from_acc(acc, sc):
 
 
 def _sample_surplus_from_acc(acc, aux, sc):
-    """Margin surplus y*(u+b1) - 1 - slack from the 2 transposed reductions."""
-    u_part, x_sq = acc[:, 0], acc[:, 1]
-    y, u_prev = aux[:, 0], aux[:, 1]
+    """Margin surplus y*(u+b1) - 1 - slack from the 2 transposed reduction
+    rows of ``acc`` and the ``[y, u_prev]`` rows of ``aux``. (1, bn)."""
+    u_part, x_sq = acc[0:1], acc[1:2]
+    y, u_prev = aux[0:1], aux[1:2]
     b1, dw, db = sc[0], sc[1], sc[2]
     shrink, floor, has_hist = sc[3], sc[4], sc[5]
 
@@ -139,7 +155,13 @@ def _sample_surplus_from_acc(acc, aux, sc):
 # kernels
 # --------------------------------------------------------------------------
 
-def _feature_kernel(x_ref, rhs_ref, sc_ref, out_ref, acc_ref, *, n_steps: int):
+def _with_sq_row(dots, sq, row: int):
+    """``dots`` with row ``row`` replaced by the squared-norm row of ``sq``."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, dots.shape, 0)
+    return jnp.where(rows == row, sq, dots)
+
+
+def _feature_kernel(x_ref, side_ref, sc_ref, out_ref, acc_ref):
     """Grid = (m_blocks, n_blocks); sample axis (dim 1) is the reduction."""
     j = pl.program_id(1)
 
@@ -148,22 +170,20 @@ def _feature_kernel(x_ref, rhs_ref, sc_ref, out_ref, acc_ref, *, n_steps: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (bm, bn)
-    rhs = rhs_ref[...].astype(jnp.float32)      # (bn, 4) cols: y*theta, y, 1, 0
-    # dots via MXU; the 4th accumulator column is ||f||^2 via elementwise.
-    dots = jax.lax.dot_general(
-        x, rhs, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                            # (bm, 4); col 3 is zero
-    sq = jnp.sum(x * x, axis=1)                  # (bm,)
-    upd = dots.at[:, 3].add(sq)
-    acc_ref[...] += upd
+    side = side_ref[...]                         # (8, bn) rows: y*theta, y, 1
+    nt = (((1,), (1,)), ((), ()))
+    dots = jax.lax.dot_general(side, x, nt, precision=_HI,
+                               preferred_element_type=jnp.float32)  # (8, bm)
+    sq = jax.lax.dot_general(jnp.ones_like(side), x * x, nt, precision=_HI,
+                             preferred_element_type=jnp.float32)    # row sums
+    acc_ref[...] += _with_sq_row(dots, sq, 3)
 
-    @pl.when(j == n_steps - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        out_ref[...] = _feature_bound_from_acc(acc_ref[...], sc_ref[...])
+        out_ref[...] = _feature_bound_from_acc(acc_ref[...], sc_ref)
 
 
-def _sample_kernel(x_ref, lhs_ref, aux_ref, sc_ref, out_ref, acc_ref, *,
-                   n_steps: int):
+def _sample_kernel(x_ref, side_ref, aux_ref, sc_ref, out_ref, acc_ref):
     """Grid = (n_blocks, m_blocks); feature axis (dim 1) is the reduction."""
     j = pl.program_id(1)
 
@@ -172,19 +192,18 @@ def _sample_kernel(x_ref, lhs_ref, aux_ref, sc_ref, out_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (bm, bn) — transposed sweep
-    lhs = lhs_ref[...].astype(jnp.float32)      # (bm, 4) cols: w1, 0, 0, 0
-    dots = jax.lax.dot_general(
-        x, lhs, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                            # (bn, 4); col 0 is u partial
-    sq = jnp.sum(x * x, axis=0)                  # (bn,) col sums: ||x_i||^2
-    upd = dots.at[:, 1].add(sq)
-    acc_ref[...] += upd
+    side = side_ref[...]                         # (8, bm) rows: w1
+    nn = (((1,), (0,)), ((), ()))
+    dots = jax.lax.dot_general(side, x, nn, precision=_HI,
+                               preferred_element_type=jnp.float32)  # (8, bn)
+    sq = jax.lax.dot_general(jnp.ones_like(side), x * x, nn, precision=_HI,
+                             preferred_element_type=jnp.float32)    # col sums
+    acc_ref[...] += _with_sq_row(dots, sq, 1)
 
-    @pl.when(j == n_steps - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        out_ref[...] = _sample_surplus_from_acc(
-            acc_ref[...], aux_ref[...], sc_ref[...]
-        )
+        out_ref[...] = _sample_surplus_from_acc(acc_ref[...], aux_ref[...],
+                                                sc_ref)
 
 
 # --------------------------------------------------------------------------
@@ -196,7 +215,7 @@ def _sample_kernel(x_ref, lhs_ref, aux_ref, sc_ref, out_ref, acc_ref, *,
 )
 def screen_bounds_pallas(
     X: jax.Array,
-    rhs: jax.Array,
+    side: jax.Array,
     scalars: jax.Array,
     aux: jax.Array | None = None,
     axis: str = "features",
@@ -206,53 +225,52 @@ def screen_bounds_pallas(
 ) -> jax.Array:
     """Fused per-unit screening bounds; ``axis`` picks the reduction axis.
 
-    ``axis="features"``: ``rhs`` is the (n, 4) stacked
-    ``[y*theta1, y, ones, zeros]``, ``scalars`` packs ScreenShared
-    (``pack_shared``), ``aux`` unused; returns (m,) VI bounds.
+    ``axis="features"``: ``side`` is the (8, n) ``stack_rows(y*theta1, y,
+    ones)``, ``scalars`` packs ScreenShared (``pack_shared``), ``aux``
+    unused; returns (m,) VI bounds.
 
-    ``axis="samples"``: ``rhs`` is the (m, 4) stacked ``[w1, 0, 0, 0]``,
-    ``aux`` is the (n, 2) stacked ``[y, u_prev]``, ``scalars`` packs the
-    slack model (``pack_sample_scalars``); returns (n,) margin surpluses.
+    ``axis="samples"``: ``side`` is the (8, m) ``stack_rows(w1)``, ``aux``
+    is the (8, n) ``stack_rows(y, u_prev)``, ``scalars`` packs the slack
+    model (``pack_sample_scalars``); returns (n,) margin surpluses.
 
     X is (m, n), pre-padded to block multiples (see kernels/ops.py).
     """
     m, n = X.shape
     assert m % block_m == 0 and n % block_n == 0, (m, n, block_m, block_n)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     if axis == "features":
         grid = (m // block_m, n // block_n)
-        kernel = functools.partial(_feature_kernel, n_steps=grid[1])
         return pl.pallas_call(
-            kernel,
+            _feature_kernel,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
-                pl.BlockSpec((block_n, 4), lambda i, j: (j, 0)),
-                pl.BlockSpec((NUM_SCALARS,), lambda i, j: (0,)),
+                pl.BlockSpec((SIDE_ROWS, block_n), lambda i, j: (0, j)),
+                smem,
             ],
-            out_specs=pl.BlockSpec((block_m,), lambda i, j: (i,)),
-            out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((block_m, 4), jnp.float32)],
+            out_specs=pl.BlockSpec((1, block_m), lambda i, j: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((SIDE_ROWS, block_m), jnp.float32)],
             interpret=interpret,
-        )(X, rhs, scalars)
+        )(X, side, scalars)[0]
 
     if axis == "samples":
-        assert aux is not None, "sample axis needs aux = stack([y, u_prev])"
+        assert aux is not None, "sample axis needs aux = stack_rows(y, u_prev)"
         grid = (n // block_n, m // block_m)
-        kernel = functools.partial(_sample_kernel, n_steps=grid[1])
         return pl.pallas_call(
-            kernel,
+            _sample_kernel,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_n), lambda i, j: (j, i)),
-                pl.BlockSpec((block_m, 4), lambda i, j: (j, 0)),
-                pl.BlockSpec((block_n, 2), lambda i, j: (i, 0)),
-                pl.BlockSpec((NUM_SCALARS,), lambda i, j: (0,)),
+                pl.BlockSpec((SIDE_ROWS, block_m), lambda i, j: (0, j)),
+                pl.BlockSpec((SIDE_ROWS, block_n), lambda i, j: (0, i)),
+                smem,
             ],
-            out_specs=pl.BlockSpec((block_n,), lambda i, j: (i,)),
-            out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((block_n, 4), jnp.float32)],
+            out_specs=pl.BlockSpec((1, block_n), lambda i, j: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((SIDE_ROWS, block_n), jnp.float32)],
             interpret=interpret,
-        )(X, rhs, aux, scalars)
+        )(X, side, aux, scalars)[0]
 
     raise ValueError(f"axis must be 'features' or 'samples', got {axis!r}")

@@ -94,7 +94,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.core.dual import bias_at_lambda_max, lambda_max, theta_at_lambda_max
+from repro.core.linalg import dot
 from repro.core.path import PathResult, _bucket, _validate_grid, default_lambda_grid
 from repro.core.path_scan import (
     ScanPathOutputs,
@@ -237,11 +239,8 @@ class PathServer:
 
     def cache_stats(self) -> dict:
         """Warm-cache health: compiled programs, hits/misses, retraces."""
-        retraces = 0
-        for fn in self._programs.values():
-            probe = getattr(fn, "_cache_size", None)
-            if probe:
-                retraces += max(0, int(probe()) - 1)
+        retraces = sum(max(0, fn._cache_size() - 1)
+                       for fn in self._programs.values())
         return dict(programs=len(self._programs), hits=self.stats["hits"],
                     misses=self.stats["misses"], retraces=retraces)
 
@@ -319,9 +318,9 @@ class PathServer:
         # padded rows/cols are zero, so sigma_max is the true problem's
         L = jnp.maximum(lipschitz_estimate(Xpj) * 1.01, 1e-12)
         # hoisted screen reductions (path_scan._batched_statics, per slot)
-        d_one = Xpj @ ypj
-        d_y = Xpj @ smj
-        d_sq = (Xpj * Xpj) @ smj
+        d_one = dot(Xpj, ypj)
+        d_y = dot(Xpj, smj)
+        d_sq = dot(Xpj * Xpj, smj)
         one_y = jnp.sum(ypj * smj)
         n_tot = jnp.sum(smj)
 
@@ -726,6 +725,7 @@ def main():
     args = ap.parse_args()
 
     log_setup()
+    enable_compile_cache()
     server = PathServer(slots=args.slots, reduce=args.reduce, tol=args.tol)
     jobs = demo_jobs(args.jobs, m=args.m, n=args.n)
     results = server.serve(jobs)

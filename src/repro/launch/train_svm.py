@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs import trace as obs_trace
 from repro.obs.log import get_logger, setup as log_setup
 
@@ -477,6 +478,7 @@ def main():
     args = ap.parse_args()
 
     log_setup()
+    enable_compile_cache()
     _obs_begin(args)
     try:
         _run(args, ap)

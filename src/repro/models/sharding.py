@@ -37,24 +37,17 @@ def batch_axes(mesh: Mesh):
 _MODEL_ROLES = ("heads", "vocab", "expert", "ffn")
 
 
-def _ambient_mesh():
-    """Compat: jax>=0.5 ``get_abstract_mesh``; older jax has no ambient-mesh
-    API, which is indistinguishable from "no mesh set" (the no-op path)."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    return getter() if getter is not None else None
-
-
 def model_axis_size() -> int:
     """Size of the ambient mesh's "model" axis (0 when no mesh is set)."""
-    am = _ambient_mesh()
-    if am is None or getattr(am, "empty", True) or "model" not in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or "model" not in am.axis_names:
         return 0
     return int(dict(am.shape)["model"])
 
 
 def logical_constraint(x, *roles):
-    am = _ambient_mesh()
-    if am is None or getattr(am, "empty", True) or "model" not in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or "model" not in am.axis_names:
         return x
     sizes = dict(am.shape)
     ba = tuple(a for a in ("pod", "data") if a in am.axis_names)

@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.linalg import dot
 from repro.obs import metrics as obs_metrics
 
 __all__ = ["CsrChunk", "FeatureChunked", "BCOO_DENSITY_THRESHOLD",
@@ -767,7 +768,7 @@ class FeatureChunked:
 
 @jax.jit
 def _chunk_mv(Xc, v):
-    return Xc @ v
+    return dot(Xc, v)
 
 
 @jax.jit
@@ -775,8 +776,8 @@ def _chunk_rmv(Xc, wc):
     # dense (rows, n).T @ (rows,) and BCOO both support this contraction;
     # for BCOO the vector-matrix form avoids materializing the transpose
     if isinstance(Xc, jnp.ndarray):
-        return Xc.T @ wc
-    return wc @ Xc
+        return dot(Xc.T, wc)
+    return dot(wc, Xc)
 
 
 @jax.jit

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.linalg import dot
 from repro.obs import trace as obs_trace
 
 from repro.core.screening import (
@@ -404,13 +405,14 @@ def gap_theta_delta_stream(
 
     for _ in range(n_feas_iters):
         alpha, _ = rescale(alpha)
-        alpha = jnp.maximum(0.0, alpha - (alpha @ y) / n * y)
+        alpha = jnp.maximum(0.0, alpha - dot(alpha, y) / n * y)
     alpha, corr = rescale(alpha)
 
     gap = (0.5 * jnp.sum(xi * xi)
            + lam * jnp.sum(jnp.abs(jnp.asarray(w, fc.dtype)))
            - (jnp.sum(alpha) - 0.5 * jnp.sum(alpha * alpha)))
-    eq_resid = jnp.abs(alpha @ y) / jnp.sqrt(jnp.asarray(float(n), fc.dtype))
+    eq_resid = (jnp.abs(dot(alpha, y))
+                / jnp.sqrt(jnp.asarray(float(n), fc.dtype)))
     delta = (jnp.sqrt(2.0 * jnp.maximum(gap, 0.0)) + 2.0 * eq_resid) / lam
     theta = alpha / lam
     # certificate sanitize (twin of solver.gap_theta_delta): any non-finite

@@ -259,9 +259,7 @@ def test_dynamic_sample_path_exact_with_verification(inst):
 # -- S1: dtype ---------------------------------------------------------------
 
 def test_sample_margin_surplus_respects_x64():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         n = 32
         rng = np.random.default_rng(0)
         X = jnp.asarray(rng.standard_normal((8, n)))

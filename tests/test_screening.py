@@ -128,21 +128,12 @@ def test_screening_becomes_aggressive_near_lambda_max():
     assert rates[0] > 0.5  # near lam_max almost everything screens out
 
 
-def _enable_x64():
-    """Compat: jax>=0.5 ``jax.enable_x64``; older jax has it in experimental."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(True)
-    from jax.experimental import enable_x64
-
-    return enable_x64()
-
-
 def test_bounds_dtype_stability():
     """fp32 vs fp64 bounds agree to fp32 tolerance (safety under rounding)."""
     X, y, lmax = _setup(200, 100, seed=13)
     theta1 = theta_at_lambda_max(y, jnp.asarray(lmax))
     b32 = np.asarray(screen_bounds(X, y, lmax, 0.3 * lmax, theta1))
-    with _enable_x64():
+    with jax.enable_x64(True):
         X64 = jnp.asarray(np.asarray(X), jnp.float64)
         y64 = jnp.asarray(np.asarray(y), jnp.float64)
         t64 = theta_at_lambda_max(y64, jnp.asarray(lmax, jnp.float64))

@@ -249,15 +249,8 @@ def _walk_avals(jaxpr):
             aval = getattr(v, "aval", None)
             if aval is not None and hasattr(aval, "shape"):
                 yield aval
-        for sub in jax.core.jaxprs_in_params(eqn.params) \
-                if hasattr(jax.core, "jaxprs_in_params") else []:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
             yield from _walk_avals(sub)
-        for name in ("jaxpr", "call_jaxpr", "cond_jaxpr", "body_jaxpr"):
-            sub = eqn.params.get(name)
-            if sub is not None:
-                yield from _walk_avals(getattr(sub, "jaxpr", sub))
-        for sub in eqn.params.get("branches", ()) or ():
-            yield from _walk_avals(getattr(sub, "jaxpr", sub))
 
 
 def test_no_full_matrix_in_chunk_jaxprs(dense_inst):
