@@ -46,7 +46,10 @@
 #                                #          round-trip, disabled-mode no-op,
 #                                #          metrics registry mirroring the
 #                                #          legacy stats dicts bitwise,
-#                                #          PathTrace schema across engines)
+#                                #          PathTrace schema across engines;
+#                                #          tests/test_path_spans.py: entry
+#                                #          spans on the profiler, scopes in
+#                                #          the program, binding rounds)
 #                                #          + a train_svm --trace smoke that
 #                                #          validates the exported Chrome
 #                                #          trace JSON
@@ -128,16 +131,17 @@ run_lane() {
         tests/test_faults.py "$@"
       ;;
     obs)
-      python -m pytest -x -q tests/test_obs.py "$@"
+      python -m pytest -x -q tests/test_obs.py tests/test_path_spans.py "$@"
       # trace-capture smoke: the launcher must export loadable Chrome
-      # trace-event JSON with per-step spans from the scan engine
+      # trace-event JSON holding the scan engine's dispatch span
       python -m repro.launch.train_svm --m 120 --n 60 --n-lambdas 4 \
         --engine scan --trace artifacts/ci_trace.json
       python - <<'EOF'
 import json
 doc = json.load(open("artifacts/ci_trace.json"))
 evs = doc["traceEvents"]
-assert any(e.get("ph") == "X" and e["name"] == "scan.step" for e in evs), \
+assert any(e.get("ph") == "X" and e["name"] == "svm_path.dispatch"
+           for e in evs), \
     sorted({e["name"] for e in evs})
 print(f"obs smoke: {len(evs)} trace events OK")
 EOF

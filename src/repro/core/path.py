@@ -50,6 +50,7 @@ round). ``exact_lipschitz=True`` restores the per-solve estimate.
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
@@ -59,8 +60,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 from repro.obs.path_trace import build_path_trace
+from repro.obs.trace import span
 
 from .dual import (
     bias_at_lambda_max,
@@ -400,142 +401,147 @@ class PathDriver:
 
         for k in range(1, T):
             lam = float(lambdas[k])
-            t0 = time.perf_counter()
+            with span("path.step", step=k, lam=lam) as step_span:
+                t0 = time.perf_counter()
 
-            # -- screening: one region, every rule --------------------------
-            st0 = time.perf_counter()
-            f_mask = np.ones((m,), dtype=bool)
-            s_mask = np.ones((n,), dtype=bool)
-            step_rules: dict[str, dict] = {}
-            if self.rules and not anchor_ok:
-                # fail-safe: the previous step's certificate was non-finite,
-                # so no region exists — keep every feature and sample this
-                # step (screening degrades to "no speedup", never to a wrong
-                # discard) and record the refusal
-                health[k] |= HEALTH_SCREEN_REFUSED
-            elif self.rules:
-                region = ConvexRegion.build(
-                    y, lam_prev, lam, theta_prev, delta=delta_prev,
-                    w1=jnp.asarray(w_host, X.dtype), b1=b_host,
-                    dw=dw_pred, db=db_pred,
-                )
+                with span("path.screen", step=k) as screen_span:
+                    # -- screening: one region, every rule --------------------
+                    st0 = time.perf_counter()
+                    f_mask = np.ones((m,), dtype=bool)
+                    s_mask = np.ones((n,), dtype=bool)
+                    step_rules: dict[str, dict] = {}
+                    if self.rules and not anchor_ok:
+                        # fail-safe: the previous step's certificate was
+                        # non-finite, so no region exists — keep every feature
+                        # and sample this step (screening degrades to "no
+                        # speedup", never to a wrong discard) and record the
+                        # refusal
+                        health[k] |= HEALTH_SCREEN_REFUSED
+                    elif self.rules:
+                        region = ConvexRegion.build(
+                            y, lam_prev, lam, theta_prev, delta=delta_prev,
+                            w1=jnp.asarray(w_host, X.dtype), b1=b_host,
+                            dw=dw_pred, db=db_pred,
+                        )
+                        for rule in feature_rules:
+                            rb = rule.bounds(X, y, region)
+                            rk = np.asarray(rule.keep(rb))
+                            f_mask &= rk
+                            rb_np = np.asarray(rb, np.float64)
+                            step_rules[rule.name] = {
+                                "kept": int(rk.sum()),
+                                "bound_mean": (float(rb_np.mean())
+                                               if rb_np.size else 0.0),
+                            }
+                        for rule in sample_rules:
+                            s_mask &= np.asarray(
+                                rule.keep(rule.bounds(X, y, region)))
+                    s_times[k] = time.perf_counter() - st0
+                    rule_log.append(step_rules)
+
+                    f_idx = np.nonzero(f_mask)[0]
+                    kept[k] = len(f_idx)
+                    screen_span.set(kept=int(kept[k]))
+
+                with span("path.solve", step=k) as solve_span:
+                    # -- solve + verification loop ----------------------------
+                    warm = {"w": w_host, "b": b_host, "rounds": 0}
+
+                    skw = None
+                    if dyn_sample_rule is not None:
+                        # the in-solver sample screen uses the same slack model
+                        # the rule screens with between lambdas: the driver's
+                        # trust radii plus the secant anchored at this step's
+                        # margins (rule.bounds above just updated _u_prev to
+                        # them)
+                        skw = dict(
+                            dynamic_samples=True,
+                            sample_dw=dw_pred, sample_db=db_pred,
+                            sample_u_prev=dyn_sample_rule._u_prev,
+                            sample_shrink_factor=dyn_sample_rule.shrink_factor,
+                            sample_margin_floor=dyn_sample_rule.margin_floor,
+                        )
+
+                    def solve(mask):
+                        s_idx = np.nonzero(mask)[0]
+                        # in-solver sample screening only on the first round: a
+                        # verification re-solve must not re-drop the violators
+                        # it was asked to re-admit
+                        res, w_full = self._solve_reduced(
+                            X, y, X_np, lam, f_mask, f_idx, mask, s_idx,
+                            warm["w"], warm["b"], L_path,
+                            sample_screen_kw=(skw if warm["rounds"] == 0
+                                              else None),
+                        )
+                        warm["w"], warm["b"] = w_full, float(res.b)
+                        warm["rounds"] += 1
+                        if getattr(res, "sample_mask", None) is not None:
+                            # fold the in-solver drops into the step's screened
+                            # set so the verification pass below covers them
+                            # too
+                            mask &= np.asarray(res.sample_mask)
+                        return res, w_full, float(res.b)
+
+                    res, w_full, b_new, rounds = solve_with_verification(
+                        solve, sample_rules, X_np, y_np, s_mask,
+                        max_rounds=self.max_verify_rounds,
+                    )
+
+                    kept_s[k] = int(s_mask.sum())
+                    vrounds[k] = rounds
+                    if sample_rules:
+                        sample_masks[k] = s_mask.copy()
+                    if isinstance(res, DynamicFistaResult):
+                        dyn_log[k] = _dynamic_telemetry(res)
+                    if getattr(res, "health", None) is not None:
+                        health[k] |= int(res.health)
+
+                    if self._fault_injector is not None:
+                        w_full, b_new = self._fault_injector(k, w_full, b_new)
+
+                    # -- movement estimates for the next step's trust region --
+                    # (weights[k-1]/biases[k-1] hold the previous accepted
+                    # solution; at k=1 that is the closed form w=0, b=b* at
+                    # lam_max)
+                    dw_pred = self.shrink_factor * float(
+                        np.linalg.norm(w_full - weights[k - 1]))
+                    db_pred = self.shrink_factor * abs(b_new - biases[k - 1])
+
+                    b_host = b_new
+                    w_host = w_full.copy()
+                    solve_span.set(iters=int(res.n_iters))
+
+                with span("path.certify", step=k):
+                    ct0 = time.perf_counter()
+                    theta_prev, delta_prev = safe_theta_and_delta(
+                        X, y, jnp.asarray(w_full, X.dtype),
+                        jnp.asarray(b_host, X.dtype),
+                        jnp.asarray(lam),
+                    )
+                    anchor_ok = _anchor_ok(theta_prev, delta_prev)
+                    deltas_log[k] = float(delta_prev)
+                    lam_prev = lam
+
+                    weights[k] = w_full
+                    biases[k] = b_host
+                    objectives[k] = float(res.obj)
+                    active[k] = int(np.sum(np.abs(w_full) > 1e-10))
+                    iters[k] = int(res.n_iters)
+                    # the certificate dispatch above is async — block so the
+                    # step's wall time covers all device work it caused, not
+                    # just what the host happened to wait for
+                    jax.block_until_ready((theta_prev, delta_prev))
+                    c_times[k] = time.perf_counter() - ct0
+                wall[k] = time.perf_counter() - t0
+                step_span.set(kept=int(kept[k]), active=int(active[k]))
+
+                # telemetry hand-back: rules exposing ``observe`` (AutoRule's
+                # cost model) learn this step's solve wall per kept feature
+                solve_s = max(wall[k] - s_times[k], 0.0)
                 for rule in feature_rules:
-                    rb = rule.bounds(X, y, region)
-                    rk = np.asarray(rule.keep(rb))
-                    f_mask &= rk
-                    rb_np = np.asarray(rb, np.float64)
-                    step_rules[rule.name] = {
-                        "kept": int(rk.sum()),
-                        "bound_mean": float(rb_np.mean()) if rb_np.size else 0.0,
-                    }
-                for rule in sample_rules:
-                    s_mask &= np.asarray(rule.keep(rule.bounds(X, y, region)))
-            s_times[k] = time.perf_counter() - st0
-            rule_log.append(step_rules)
-
-            f_idx = np.nonzero(f_mask)[0]
-            kept[k] = len(f_idx)
-
-            # -- solve + verification loop ----------------------------------
-            warm = {"w": w_host, "b": b_host, "rounds": 0}
-
-            skw = None
-            if dyn_sample_rule is not None:
-                # the in-solver sample screen uses the same slack model the
-                # rule screens with between lambdas: the driver's trust
-                # radii plus the secant anchored at this step's margins
-                # (rule.bounds above just updated _u_prev to them)
-                skw = dict(
-                    dynamic_samples=True,
-                    sample_dw=dw_pred, sample_db=db_pred,
-                    sample_u_prev=dyn_sample_rule._u_prev,
-                    sample_shrink_factor=dyn_sample_rule.shrink_factor,
-                    sample_margin_floor=dyn_sample_rule.margin_floor,
-                )
-
-            def solve(mask):
-                s_idx = np.nonzero(mask)[0]
-                # in-solver sample screening only on the first round: a
-                # verification re-solve must not re-drop the violators it
-                # was asked to re-admit
-                res, w_full = self._solve_reduced(
-                    X, y, X_np, lam, f_mask, f_idx, mask, s_idx,
-                    warm["w"], warm["b"], L_path,
-                    sample_screen_kw=skw if warm["rounds"] == 0 else None,
-                )
-                warm["w"], warm["b"] = w_full, float(res.b)
-                warm["rounds"] += 1
-                if getattr(res, "sample_mask", None) is not None:
-                    # fold the in-solver drops into the step's screened set
-                    # so the verification pass below covers them too
-                    mask &= np.asarray(res.sample_mask)
-                return res, w_full, float(res.b)
-
-            res, w_full, b_new, rounds = solve_with_verification(
-                solve, sample_rules, X_np, y_np, s_mask,
-                max_rounds=self.max_verify_rounds,
-            )
-
-            kept_s[k] = int(s_mask.sum())
-            vrounds[k] = rounds
-            if sample_rules:
-                sample_masks[k] = s_mask.copy()
-            if isinstance(res, DynamicFistaResult):
-                dyn_log[k] = _dynamic_telemetry(res)
-            if getattr(res, "health", None) is not None:
-                health[k] |= int(res.health)
-
-            if self._fault_injector is not None:
-                w_full, b_new = self._fault_injector(k, w_full, b_new)
-
-            # -- movement estimates for the next step's trust region --------
-            # (weights[k-1]/biases[k-1] hold the previous accepted solution;
-            # at k=1 that is the closed form w=0, b=b* at lam_max)
-            dw_pred = self.shrink_factor * float(np.linalg.norm(w_full - weights[k - 1]))
-            db_pred = self.shrink_factor * abs(b_new - biases[k - 1])
-
-            b_host = b_new
-            w_host = w_full.copy()
-
-            ct0 = time.perf_counter()
-            theta_prev, delta_prev = safe_theta_and_delta(
-                X, y, jnp.asarray(w_full, X.dtype), jnp.asarray(b_host, X.dtype),
-                jnp.asarray(lam),
-            )
-            anchor_ok = _anchor_ok(theta_prev, delta_prev)
-            deltas_log[k] = float(delta_prev)
-            lam_prev = lam
-
-            weights[k] = w_full
-            biases[k] = b_host
-            objectives[k] = float(res.obj)
-            active[k] = int(np.sum(np.abs(w_full) > 1e-10))
-            iters[k] = int(res.n_iters)
-            # the certificate dispatch above is async — block so the step's
-            # wall time covers all device work it caused, not just what the
-            # host happened to wait for
-            jax.block_until_ready((theta_prev, delta_prev))
-            c_times[k] = time.perf_counter() - ct0
-            wall[k] = time.perf_counter() - t0
-            if obs_trace.enabled():
-                st1 = st0 + s_times[k]
-                obs_trace.complete("path.screen", st0, st1, step=k,
-                                   kept=int(kept[k]))
-                obs_trace.complete("path.solve", st1, ct0, step=k,
-                                   iters=int(iters[k]))
-                obs_trace.complete("path.certify", ct0, ct0 + c_times[k],
-                                   step=k)
-                obs_trace.complete("path.step", t0, t0 + wall[k], step=k,
-                                   lam=lam, kept=int(kept[k]),
-                                   active=int(active[k]))
-
-            # telemetry hand-back: rules exposing ``observe`` (AutoRule's
-            # cost model) learn this step's solve wall per kept feature
-            solve_s = max(wall[k] - s_times[k], 0.0)
-            for rule in feature_rules:
-                obs = getattr(rule, "observe", None)
-                if obs is not None:
-                    obs(solve_seconds=solve_s, kept=int(kept[k]))
+                    obs = getattr(rule, "observe", None)
+                    if obs is not None:
+                        obs(solve_seconds=solve_s, kept=int(kept[k]))
 
         kept_s[0] = 0
         self._observe_run("host", kept, health)
@@ -792,173 +798,181 @@ class PathDriver:
 
         for k in range(1, T):
             lam = float(lambdas[k])
-            t0 = time.perf_counter()
+            with span("path.step", step=k, lam=lam) as step_span:
+                t0 = time.perf_counter()
 
-            st0 = time.perf_counter()
-            s_mask = np.ones((n,), dtype=bool)
-            live = np.ones((fc.n_chunks,), dtype=bool)
-            if feature_rules and not anchor_ok:
-                # fail-safe: no finite certificate to screen from — keep
-                # every feature and stream every chunk this step (cf. run())
-                health[k] |= HEALTH_SCREEN_REFUSED
-                f_mask = np.ones((m,), dtype=bool)
-            elif feature_rules:
-                keep_m, _, anchor, live = screen_step_stream(
-                    fc, y, lam_prev, lam, theta_prev, delta=delta_prev,
-                    rules=progs, tau=tau, cache=cache,
-                    anchor_old=anchor_old, skip=self.chunk_skip,
-                    use_pallas=self.use_pallas,
-                )
-                if needs_hist:
-                    # last step's fresh anchor is this step's old — free
-                    anchor_old = anchor
-                f_mask = np.asarray(keep_m)
-                live_log[k] = int(live.sum())
-            else:
-                f_mask = np.ones((m,), dtype=bool)
-            if sample_rules:
-                # transposed sweep: margins + column norms, zero streams
-                u1 = (jnp.asarray(u_carry, fc.dtype)
-                      + jnp.asarray(b_host, fc.dtype))
-                for rule in sample_rules:
-                    surplus = margin_surplus_core(
-                        u1, yd, x_sq, dw_pred, db_pred,
-                        u_prev=rule._u_prev,
-                        shrink_factor=rule.shrink_factor,
-                        margin_floor=rule.margin_floor,
+                with span("path.screen", step=k) as screen_span:
+                    st0 = time.perf_counter()
+                    s_mask = np.ones((n,), dtype=bool)
+                    live = np.ones((fc.n_chunks,), dtype=bool)
+                    if feature_rules and not anchor_ok:
+                        # fail-safe: no finite certificate to screen from —
+                        # keep every feature and stream every chunk this step
+                        # (cf. run())
+                        health[k] |= HEALTH_SCREEN_REFUSED
+                        f_mask = np.ones((m,), dtype=bool)
+                    elif feature_rules:
+                        keep_m, _, anchor, live = screen_step_stream(
+                            fc, y, lam_prev, lam, theta_prev, delta=delta_prev,
+                            rules=progs, tau=tau, cache=cache,
+                            anchor_old=anchor_old, skip=self.chunk_skip,
+                            use_pallas=self.use_pallas,
+                        )
+                        if needs_hist:
+                            # last step's fresh anchor is this step's old —
+                            # free
+                            anchor_old = anchor
+                        f_mask = np.asarray(keep_m)
+                        live_log[k] = int(live.sum())
+                    else:
+                        f_mask = np.ones((m,), dtype=bool)
+                    if sample_rules:
+                        # transposed sweep: margins + column norms, zero
+                        # streams
+                        u1 = (jnp.asarray(u_carry, fc.dtype)
+                              + jnp.asarray(b_host, fc.dtype))
+                        for rule in sample_rules:
+                            surplus = margin_surplus_core(
+                                u1, yd, x_sq, dw_pred, db_pred,
+                                u_prev=rule._u_prev,
+                                shrink_factor=rule.shrink_factor,
+                                margin_floor=rule.margin_floor,
+                            )
+                            # secant anchor for the next step
+                            rule._u_prev = u1
+                            # NaN-safe drop test (cf. solver._dynamic_run): a
+                            # non-finite surplus keeps the sample — a poisoned
+                            # margin costs verification rounds, never loss
+                            # terms
+                            s_mask &= np.asarray(~(surplus >= 0.0))
+                    s_times[k] = time.perf_counter() - st0
+
+                    f_idx = np.nonzero(f_mask)[0]
+                    kept[k] = len(f_idx)
+                    screen_span.set(kept=int(kept[k]))
+
+                with span("path.solve", step=k) as solve_span:
+                    # -- solve + sample verification (cf.
+                    # solve_with_verification):
+                    # the feature mask is a-priori safe and fixed for the step,
+                    # so the gather happens once; only the sample mask changes
+                    # per verification round, and the margin re-check rides the
+                    # solve's own carried u — no extra stream either way.
+                    if not self.dynamic:
+                        sel_f, valid_f = self._feature_select(None, f_idx, m)
+                        Xr = jnp.asarray(fc.gather_rows(sel_f)
+                                         * valid_f[:, None].astype(fc.dtype))
+                    warm_w, warm_b = w_host, b_host
+                    rounds = 0
+                    while True:
+                        smask_dev = (None if s_mask.all()
+                                     else jnp.asarray(s_mask.astype(fc.dtype)))
+                        if self.dynamic:
+                            rep: dict = {}
+                            res = fista_solve_chunked(
+                                fc, y, lam,
+                                w0=jnp.asarray(
+                                    (warm_w * f_mask).astype(fc.dtype)),
+                                b0=jnp.asarray(warm_b, fc.dtype),
+                                max_iters=self.max_iters, tol=self.tol,
+                                L=L_path, sample_mask=smask_dev,
+                                feature_mask=f_mask,
+                                report=rep, guards=self.guards, **dyn_kw,
+                            )
+                            w_full = np.asarray(res.w, dtype=np.float64)
+                            dyn_log[k] = rep
+                        else:
+                            w0 = jnp.asarray(
+                                (warm_w[sel_f] * valid_f).astype(fc.dtype))
+                            res = fista_solve(
+                                Xr, y, jnp.asarray(lam), w0=w0,
+                                b0=jnp.asarray(warm_b, fc.dtype),
+                                max_iters=self.max_iters, tol=self.tol,
+                                L=L_path, sample_mask=smask_dev,
+                                use_pallas=self.use_pallas,
+                                guards=self.guards,
+                            )
+                            w_full = np.zeros((m,), dtype=np.float64)
+                            w_full[sel_f[: len(f_idx)]] = (
+                                np.asarray(res.w, np.float64)[: len(f_idx)])
+                        b_new = float(res.b)
+                        warm_w, warm_b = w_full, b_new
+                        if s_mask.all() or not sample_rules:
+                            break
+                        scr = np.nonzero(~s_mask)[0]
+                        u_np = np.asarray(res.u, dtype=np.float64)
+                        viol = np.asarray(violators_from_margins(
+                            y_np, u_np[scr] + b_new, scr))
+                        if len(viol) == 0:
+                            break
+                        rounds += 1
+                        if rounds >= self.max_verify_rounds:
+                            s_mask[:] = True  # give up screening: exact solve
+                        else:
+                            s_mask[viol] = True
+
+                    kept_s[k] = int(s_mask.sum())
+                    vrounds[k] = rounds
+                    if sample_rules:
+                        sample_masks[k] = s_mask.copy()
+                    if getattr(res, "health", None) is not None:
+                        health[k] |= int(res.health)
+
+                    if self._fault_injector is not None:
+                        w_full, b_new = self._fault_injector(k, w_full, b_new)
+
+                    # movement estimates for the next step's trust region
+                    dw_pred = self.shrink_factor * float(
+                        np.linalg.norm(w_full - weights[k - 1]))
+                    db_pred = self.shrink_factor * abs(b_new - biases[k - 1])
+                    b_host = b_new
+                    w_host = w_full
+                    u_carry = np.asarray(res.u, dtype=np.float64)
+
+                    # certify the accepted point as the next anchor. The margin
+                    # sweep rides the solver's carried u (exact: padding rows
+                    # are zero); the correlation sweeps stream only the
+                    # gating-live chunks — every kept feature lives in one
+                    # (dead chunks' stamped bounds are all < tau), so the
+                    # reduced-problem feasibility max is exact — and the final
+                    # sweep doubles as the fresh d_theta that re-anchors every
+                    # live chunk's cache entry: next step's gating is exactly
+                    # as sharp as its screen, at zero extra streams.
+                    live_arg = None if live.all() else live
+                    fm_cert = (None if f_mask.all()
+                               else jnp.asarray(f_mask.astype(fc.dtype)))
+                    solve_span.set(iters=int(res.n_iters))
+                with span("path.certify", step=k):
+                    ct0 = time.perf_counter()
+                    theta_prev, delta_prev, d_th = gap_theta_delta_stream(
+                        fc, y, jnp.asarray(w_full, fc.dtype), res.b,
+                        jnp.asarray(lam), u=res.u, live_chunks=live_arg,
+                        feature_mask=fm_cert, want_corr=True,
                     )
-                    rule._u_prev = u1  # secant anchor for the next step
-                    # NaN-safe drop test (cf. solver._dynamic_run): a
-                    # non-finite surplus keeps the sample — a poisoned
-                    # margin costs verification rounds, never loss terms
-                    s_mask &= np.asarray(~(surplus >= 0.0))
-            s_times[k] = time.perf_counter() - st0
+                    anchor_ok = _anchor_ok(theta_prev, delta_prev)
+                    deltas_log[k] = float(delta_prev)
+                    if feature_rules:
+                        # a poisoned anchor is safe to hand over: refresh()
+                        # guards non-finite stats by *invalidating* the touched
+                        # entries, so gating treats those chunks as
+                        # never-streamed (always live)
+                        cache.refresh(
+                            anchor_stats(yd, lam, theta_prev, delta_prev,
+                                         d_th),
+                            live=set(int(ci) for ci in np.nonzero(live)[0]),
+                        )
+                    lam_prev = lam
 
-            f_idx = np.nonzero(f_mask)[0]
-            kept[k] = len(f_idx)
-
-            # -- solve + sample verification (cf. solve_with_verification):
-            # the feature mask is a-priori safe and fixed for the step, so
-            # the gather happens once; only the sample mask changes per
-            # verification round, and the margin re-check rides the
-            # solve's own carried u — no extra stream either way.
-            if not self.dynamic:
-                sel_f, valid_f = self._feature_select(None, f_idx, m)
-                Xr = jnp.asarray(fc.gather_rows(sel_f)
-                                 * valid_f[:, None].astype(fc.dtype))
-            warm_w, warm_b = w_host, b_host
-            rounds = 0
-            while True:
-                smask_dev = (None if s_mask.all()
-                             else jnp.asarray(s_mask.astype(fc.dtype)))
-                if self.dynamic:
-                    rep: dict = {}
-                    res = fista_solve_chunked(
-                        fc, y, lam,
-                        w0=jnp.asarray((warm_w * f_mask).astype(fc.dtype)),
-                        b0=jnp.asarray(warm_b, fc.dtype),
-                        max_iters=self.max_iters, tol=self.tol, L=L_path,
-                        sample_mask=smask_dev, feature_mask=f_mask,
-                        report=rep, guards=self.guards, **dyn_kw,
-                    )
-                    w_full = np.asarray(res.w, dtype=np.float64)
-                    dyn_log[k] = rep
-                else:
-                    w0 = jnp.asarray((warm_w[sel_f] * valid_f).astype(fc.dtype))
-                    res = fista_solve(
-                        Xr, y, jnp.asarray(lam), w0=w0,
-                        b0=jnp.asarray(warm_b, fc.dtype),
-                        max_iters=self.max_iters, tol=self.tol, L=L_path,
-                        sample_mask=smask_dev, use_pallas=self.use_pallas,
-                        guards=self.guards,
-                    )
-                    w_full = np.zeros((m,), dtype=np.float64)
-                    w_full[sel_f[: len(f_idx)]] = (
-                        np.asarray(res.w, np.float64)[: len(f_idx)])
-                b_new = float(res.b)
-                warm_w, warm_b = w_full, b_new
-                if s_mask.all() or not sample_rules:
-                    break
-                scr = np.nonzero(~s_mask)[0]
-                u_np = np.asarray(res.u, dtype=np.float64)
-                viol = np.asarray(violators_from_margins(
-                    y_np, u_np[scr] + b_new, scr))
-                if len(viol) == 0:
-                    break
-                rounds += 1
-                if rounds >= self.max_verify_rounds:
-                    s_mask[:] = True  # give up screening: exact solve
-                else:
-                    s_mask[viol] = True
-
-            kept_s[k] = int(s_mask.sum())
-            vrounds[k] = rounds
-            if sample_rules:
-                sample_masks[k] = s_mask.copy()
-            if getattr(res, "health", None) is not None:
-                health[k] |= int(res.health)
-
-            if self._fault_injector is not None:
-                w_full, b_new = self._fault_injector(k, w_full, b_new)
-
-            # movement estimates for the next step's trust region
-            dw_pred = self.shrink_factor * float(
-                np.linalg.norm(w_full - weights[k - 1]))
-            db_pred = self.shrink_factor * abs(b_new - biases[k - 1])
-            b_host = b_new
-            w_host = w_full
-            u_carry = np.asarray(res.u, dtype=np.float64)
-
-            # certify the accepted point as the next anchor. The margin
-            # sweep rides the solver's carried u (exact: padding rows are
-            # zero); the correlation sweeps stream only the gating-live
-            # chunks — every kept feature lives in one (dead chunks'
-            # stamped bounds are all < tau), so the reduced-problem
-            # feasibility max is exact — and the final sweep doubles as
-            # the fresh d_theta that re-anchors every live chunk's cache
-            # entry: next step's gating is exactly as sharp as its screen,
-            # at zero extra streams.
-            live_arg = None if live.all() else live
-            fm_cert = (None if f_mask.all()
-                       else jnp.asarray(f_mask.astype(fc.dtype)))
-            ct0 = time.perf_counter()
-            theta_prev, delta_prev, d_th = gap_theta_delta_stream(
-                fc, y, jnp.asarray(w_full, fc.dtype), res.b,
-                jnp.asarray(lam), u=res.u, live_chunks=live_arg,
-                feature_mask=fm_cert, want_corr=True,
-            )
-            anchor_ok = _anchor_ok(theta_prev, delta_prev)
-            deltas_log[k] = float(delta_prev)
-            if feature_rules:
-                # a poisoned anchor is safe to hand over: refresh() guards
-                # non-finite stats by *invalidating* the touched entries, so
-                # gating treats those chunks as never-streamed (always live)
-                cache.refresh(
-                    anchor_stats(yd, lam, theta_prev, delta_prev, d_th),
-                    live=set(int(ci) for ci in np.nonzero(live)[0]),
-                )
-            lam_prev = lam
-
-            weights[k] = w_full
-            biases[k] = b_host
-            objectives[k] = float(res.obj)
-            active[k] = int(np.sum(np.abs(w_full) > 1e-10))
-            iters[k] = int(res.n_iters)
-            jax.block_until_ready((theta_prev, delta_prev))
-            c_times[k] = time.perf_counter() - ct0
-            wall[k] = time.perf_counter() - t0
-            if obs_trace.enabled():
-                st1 = st0 + s_times[k]
-                obs_trace.complete("path.screen", st0, st1, step=k,
-                                   kept=int(kept[k]),
-                                   live_chunks=int(live_log[k]))
-                obs_trace.complete("path.solve", st1, ct0, step=k,
-                                   iters=int(iters[k]))
-                obs_trace.complete("path.certify", ct0, ct0 + c_times[k],
-                                   step=k)
-                obs_trace.complete("path.step", t0, t0 + wall[k], step=k,
-                                   lam=lam, kept=int(kept[k]),
-                                   active=int(active[k]))
+                    weights[k] = w_full
+                    biases[k] = b_host
+                    objectives[k] = float(res.obj)
+                    active[k] = int(np.sum(np.abs(w_full) > 1e-10))
+                    iters[k] = int(res.n_iters)
+                    jax.block_until_ready((theta_prev, delta_prev))
+                    c_times[k] = time.perf_counter() - ct0
+                wall[k] = time.perf_counter() - t0
+                step_span.set(kept=int(kept[k]), active=int(active[k]),
+                          live_chunks=int(live_log[k]))
 
         kept_s[0] = 0
         self._observe_run("chunked", kept, health)
@@ -993,6 +1007,30 @@ class PathDriver:
         )
 
 
+def _entry(fn):
+    """Run the public entry in the ``svm_path`` span, and observe its self
+    time into ``path.entry_s``: the call's wall less the single-dispatch
+    engines' dispatch wall (``extras["total_seconds"]``, the
+    ``svm_path.dispatch`` span), so what the entry costs on the host around
+    the device program. The host engine has no single dispatch and
+    observes nothing."""
+    @functools.wraps(fn)
+    def entry(*args, **kwargs):
+        t0 = time.perf_counter()
+        with span("svm_path"):
+            r = fn(*args, **kwargs)
+        wall_s = time.perf_counter() - t0
+        first = r[0] if isinstance(r, list) else r
+        dispatch_s = first.extras.get("total_seconds")
+        if dispatch_s is not None:
+            obs_metrics.histogram("path.entry_s").observe(
+                wall_s - dispatch_s)
+        return r
+
+    return entry
+
+
+@_entry
 def svm_path(
     X: jax.Array,
     y: jax.Array,

@@ -97,15 +97,16 @@ those (including ``"sifs"``).
 from __future__ import annotations
 
 import time
-from functools import partial
+from functools import lru_cache, partial
 from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import trace as obs_trace
+from repro.obs import metrics as obs_metrics
 from repro.obs.path_trace import build_path_trace
+from repro.obs.trace import span
 
 from .dual import bias_at_lambda_max, lambda_max, theta_at_lambda_max
 from .linalg import dot
@@ -133,7 +134,7 @@ from .solver import (
     _resolve_pallas,
     _sweep_operand,
     fista_run,
-    gap_theta_delta,
+    gap_theta_delta_binding,
     lipschitz_estimate,
 )
 
@@ -167,6 +168,14 @@ class ScanPathOutputs(NamedTuple):
     # HEALTH_SCREEN_REFUSED flags a step that screened from a refused
     # (non-finite) certificate and fail-safed to keep-all. 0 = clean.
     health: jax.Array
+    # (T,) int32 — certification rounds whose feasibility rescale was
+    # binding (solver.gap_theta_delta_binding), of n_feas_iters + 1 per step
+    feas_binding: jax.Array
+
+
+#: feasibility rounds of each step's certificate before its final rescale
+#: (``solver.gap_theta_delta_binding``)
+N_FEAS_ITERS = 8
 
 
 def compact_caps(m: int, max_buckets: int = 4, min_cap: int = 32) -> tuple:
@@ -242,7 +251,7 @@ def _batched_path_step(
     use_pallas: bool,
     exact_lipschitz: bool,
     rules: tuple = ("feature_vi",),
-    n_feas_iters: int = 8,
+    n_feas_iters: int = N_FEAS_ITERS,
     guards: bool = False,
 ):
     """One batched lambda step: screen -> shared-cap solve -> certify.
@@ -346,18 +355,20 @@ def _batched_path_step(
     def make_compact_one(cap):
         def one(Xe, ye, sme, la, inv_Ls, w_, b_, fmask_):
             # same cumsum compaction as the single-path compact branch
-            pos = jnp.cumsum(fmask_.astype(jnp.int32)) - 1
-            slot = jnp.where(fmask_ > 0.5, pos, cap)
-            sel = jnp.full((cap,), m, jnp.int32).at[slot].set(
-                jnp.arange(m, dtype=jnp.int32), mode="drop")
-            validf = (sel < m).astype(dt)
-            selc = jnp.minimum(sel, m - 1)
-            Xc = jnp.take(Xe, selc, axis=0) * validf[:, None]
-            w0_c = jnp.take(w_, selc) * validf
-            vcount = jnp.sum(fmask_).astype(jnp.int32)
+            with jax.named_scope("svm_path_batched/solve/compact"):
+                pos = jnp.cumsum(fmask_.astype(jnp.int32)) - 1
+                slot = jnp.where(fmask_ > 0.5, pos, cap)
+                sel = jnp.full((cap,), m, jnp.int32).at[slot].set(
+                    jnp.arange(m, dtype=jnp.int32), mode="drop")
+                validf = (sel < m).astype(dt)
+                selc = jnp.minimum(sel, m - 1)
+                Xc = jnp.take(Xe, selc, axis=0) * validf[:, None]
+                w0_c = jnp.take(w_, selc) * validf
+                vcount = jnp.sum(fmask_).astype(jnp.int32)
             res = solve(Xc, ye, sme, la, w0_c, b_, validf,
                         inv_L_for(Xc, inv_Ls), vcount)
-            w_full = jnp.zeros((m,), dt).at[selc].add(res.w * validf)
+            with jax.named_scope("svm_path_batched/solve/compact"):
+                w_full = jnp.zeros((m,), dt).at[selc].add(res.w * validf)
             return (w_full, res.b, res.obj,
                     jnp.asarray(res.n_iters, jnp.int32), res.converged,
                     res.u, jnp.asarray(res.health, jnp.int32))
@@ -387,11 +398,12 @@ def _batched_path_step(
             cap_used = jnp.full((B,), m, jnp.int32)
 
     def certify_one(Xe, ye, sme, w_, b_, la, u_):
-        return gap_theta_delta(Xe, ye, w_, b_, la, sme,
-                               n_feas_iters=n_feas_iters, u=u_)
+        return gap_theta_delta_binding(
+            Xe, ye, w_, b_, la, sme, n_feas_iters=n_feas_iters, u=u_,
+            scope="svm_path_batched/certify/feasibility")
 
     with jax.named_scope("svm_path_batched/certify"):
-        theta2, delta2, gap = jax.vmap(
+        theta2, delta2, gap, feas_binding = jax.vmap(
             certify_one, in_axes=(ax, ax, ax, 0, 0, 0, 0))(
             X, y, sm, w2, b2, lam, u_fin)
 
@@ -402,6 +414,7 @@ def _batched_path_step(
         fmask=keep, cap=cap_used, resurrected=resurrected,
         health=health | jnp.where(
             anchor_ok, 0, HEALTH_SCREEN_REFUSED).astype(jnp.int32),
+        feas_binding=feas_binding,
     )
     new_carry = (w2, b2, theta2, delta2, lam, fmask)
     if needs_hist:
@@ -433,7 +446,7 @@ def _batched_path_scan_program(
     reduce: str = "compact",
     rules: tuple = ("feature_vi",),
     shared_x: bool = False,
-    n_feas_iters: int = 8,
+    n_feas_iters: int = N_FEAS_ITERS,
     guards: bool = False,
 ) -> ScanPathOutputs:
     """B whole paths as one program, compaction composed with batching.
@@ -461,13 +474,13 @@ def _batched_path_scan_program(
 
     if sm is None:
         sm = jnp.ones((n,), dt) if shared_x else jnp.ones((B, n), dt)
-    if L is None:
-        L = lipschitz_estimate(X) if shared_x else jax.vmap(
-            lipschitz_estimate)(X)
-    inv_L = 1.0 / jnp.maximum(
-        jnp.broadcast_to(jnp.asarray(L, dt), (B,)) * 1.01, 1e-12)
-
-    statics = _batched_statics(X, y, sm, shared_x)
+    with jax.named_scope("svm_path_batched/prologue"):
+        if L is None:
+            L = lipschitz_estimate(X) if shared_x else jax.vmap(
+                lipschitz_estimate)(X)
+        inv_L = 1.0 / jnp.maximum(
+            jnp.broadcast_to(jnp.asarray(L, dt), (B,)) * 1.01, 1e-12)
+        statics = _batched_statics(X, y, sm, shared_x)
     act = jnp.ones((B,), bool)
     step_kw = dict(
         caps=caps, shared_x=shared_x, max_iters=max_iters,
@@ -521,7 +534,7 @@ def _path_scan_program(
     reduce: str = "mask",
     rules: tuple = ("feature_vi",),
     col: Collectives = LOCAL,
-    n_feas_iters: int = 8,
+    n_feas_iters: int = N_FEAS_ITERS,
     guards: bool = False,
     sm: Optional[jax.Array] = None,
 ) -> ScanPathOutputs:
@@ -552,24 +565,27 @@ def _path_scan_program(
             "sharded collectives seam yet; use dynamic=False when sharded"
         )
 
-    if L is None:
-        L = lipschitz_estimate(X, col=col, sample_mask=sm)
-    L = jnp.maximum(L * 1.01, 1e-12)
-    inv_L = 1.0 / L
-    # the Pallas sweeps' operand, padded once per path: every mask-branch
-    # solve reads it (compact solves pad their own gathered buffer)
-    X_sweep = _sweep_operand(X, use_pallas, col)
+    # the per-call work on X before the first step, in one name scope
+    with jax.named_scope("svm_path/prologue"):
+        if L is None:
+            L = lipschitz_estimate(X, col=col, sample_mask=sm)
+        L = jnp.maximum(L * 1.01, 1e-12)
+        inv_L = 1.0 / L
+        # the Pallas sweeps' operand, padded once per path: every
+        # mask-branch solve reads it (compact solves pad their own buffer)
+        X_sweep = _sweep_operand(X, use_pallas, col)
 
-    # theta-independent screen reductions, hoisted out of the scan: per step
-    # only the O(mn) ``X @ (y * theta)`` sweep remains (paper Sec. 6.4).
-    ones = jnp.ones((n,), dt) if sm is None else sm
-    d_one = col.psum_data(dot(X, y))          # fhat_j^T 1
-    d_y = col.psum_data(dot(X, ones))         # fhat_j^T y
-    d_sq = col.psum_data(jnp.sum(X * X, axis=1))
-    one_y = col.psum_data(jnp.sum(y))
-    n_tot = col.psum_data(jnp.asarray(float(n), dt) if sm is None
-                          else jnp.sum(sm))
-    m_tot = col.psum_model(jnp.asarray(float(m), dt)).astype(jnp.int32)
+        # theta-independent screen reductions, hoisted out of the scan: per
+        # step only the O(mn) ``X @ (y * theta)`` sweep remains (paper
+        # Sec. 6.4).
+        ones = jnp.ones((n,), dt) if sm is None else sm
+        d_one = col.psum_data(dot(X, y))          # fhat_j^T 1
+        d_y = col.psum_data(dot(X, ones))         # fhat_j^T y
+        d_sq = col.psum_data(jnp.sum(X * X, axis=1))
+        one_y = col.psum_data(jnp.sum(y))
+        n_tot = col.psum_data(jnp.asarray(float(n), dt) if sm is None
+                              else jnp.sum(sm))
+        m_tot = col.psum_model(jnp.asarray(float(m), dt)).astype(jnp.int32)
 
     progs = tuple(PROGRAMS[nm] for nm in rules) if screening else ()
     needs_hist = stack_needs_history(progs)
@@ -658,26 +674,33 @@ def _path_scan_program(
         def make_compact_branch(cap):
             def branch(args):
                 w_, b_, fmask_ = args
-                # cumsum compaction: kept row j lands in slot rank(j);
-                # screened rows scatter to the dropped sentinel slot
-                pos = jnp.cumsum(fmask_.astype(jnp.int32)) - 1
-                slot = jnp.where(fmask_ > 0.5, pos, cap)
-                sel = jnp.full((cap,), m, jnp.int32).at[slot].set(
-                    jnp.arange(m, dtype=jnp.int32), mode="drop")
-                validf = (sel < m).astype(dt)
-                selc = jnp.minimum(sel, m - 1)
-                # gathered from the padded sweep operand, so the kernels
-                # read the buffer as built (Xc is its logical slice)
-                Xc_s = jnp.take(X_sweep, selc, axis=0) * validf[:, None]
-                Xc = Xc_s[:, :n]
-                # every gathered row is a certified keep, so the buffer's
-                # live mask IS the validity mask; w already respects fmask
-                # on gathered rows (screened rows are not in the buffer)
-                w0_c = jnp.take(w_, selc) * validf
-                vcount = jnp.sum(fmask_).astype(jnp.int32)
+                # the full scope name: inside the switch the name stack
+                # reads ".../svm_path/solve/cond/branch_<i>_fun/..."
+                with jax.named_scope("svm_path/solve/compact"):
+                    # cumsum compaction: kept row j lands in slot rank(j);
+                    # screened rows scatter to the dropped sentinel slot
+                    pos = jnp.cumsum(fmask_.astype(jnp.int32)) - 1
+                    slot = jnp.where(fmask_ > 0.5, pos, cap)
+                    sel = jnp.full((cap,), m, jnp.int32).at[slot].set(
+                        jnp.arange(m, dtype=jnp.int32), mode="drop")
+                    validf = (sel < m).astype(dt)
+                    selc = jnp.minimum(sel, m - 1)
+                    # gathered from the padded sweep operand, so the
+                    # kernels read the buffer as built (Xc is its logical
+                    # slice)
+                    Xc_s = jnp.take(X_sweep, selc, axis=0) * validf[:, None]
+                    Xc = Xc_s[:, :n]
+                    # every gathered row is a certified keep, so the
+                    # buffer's live mask IS the validity mask; w already
+                    # respects fmask on gathered rows (screened rows are
+                    # not in the buffer)
+                    w0_c = jnp.take(w_, selc) * validf
+                    vcount = jnp.sum(fmask_).astype(jnp.int32)
                 res = solve(Xc, w0_c, b_, validf, inv_L_for(Xc), vcount,
                             _sweep_operand(Xc_s, use_pallas, col))
-                w_full = jnp.zeros((m,), dt).at[selc].add(res.w * validf)
+                with jax.named_scope("svm_path/solve/compact"):
+                    w_full = jnp.zeros((m,), dt).at[selc].add(
+                        res.w * validf)
                 return (w_full, res.b, res.obj,
                         jnp.asarray(res.n_iters, jnp.int32), res.converged,
                         res.u, jnp.asarray(res.health, jnp.int32))
@@ -702,7 +725,7 @@ def _path_scan_program(
         # (full-X certificate — the dual feasibility max runs over every
         # feature — but the margin sweep rides the solver's carried u)
         with jax.named_scope("svm_path/certify"):
-            theta2, delta2, gap = gap_theta_delta(
+            theta2, delta2, gap, feas_binding = gap_theta_delta_binding(
                 X, y, w2, b2, lam, sm, n_feas_iters=n_feas_iters, col=col,
                 u=u_fin,
             )
@@ -718,6 +741,7 @@ def _path_scan_program(
             fmask=keep, cap=cap_used, resurrected=resurrected,
             health=health | jnp.where(
                 anchor_ok, 0, HEALTH_SCREEN_REFUSED).astype(jnp.int32),
+            feas_binding=feas_binding,
         )
         new_carry = (w2, b2, theta2, delta2, lam, fmask)
         if needs_hist:
@@ -853,6 +877,9 @@ def _to_path_result(lambdas, outs: ScanPathOutputs, lam_max_val, wall_s,
     # kept histogram), so every engine's runs aggregate in one place
     PathDriver._observe_run(engine, np.asarray(outs.kept, np.int64),
                             np.asarray(outs.health, np.int64))
+    feas_binding = np.asarray(outs.feas_binding, np.int64)
+    obs_metrics.histogram("path.certify_binding_share").observe(
+        float(feas_binding.sum()) / max(T * (N_FEAS_ITERS + 1), 1))
     return PathResult(
         lambdas=np.asarray(lambdas, np.float64),
         weights=np.asarray(outs.w, np.float64),
@@ -884,9 +911,23 @@ def _to_path_result(lambdas, outs: ScanPathOutputs, lam_max_val, wall_s,
             # per-step guard telemetry (solver.HEALTH_SCREEN_REFUSED flags a
             # fail-safe keep-all step; low bits count solver rollbacks)
             "health": np.asarray(outs.health, np.int64),
+            # per-step certification rounds whose rescale was binding, of
+            # N_FEAS_ITERS + 1
+            "feas_binding": feas_binding,
             "options": dict(static_kw),
         },
     )
+
+
+@lru_cache(maxsize=None)
+def _lambda_max_program(prefix: str):
+    """``dual.lambda_max`` as one jitted program whose ops carry the name
+    scope ``<prefix>/lambda_max``."""
+    def scoped_lambda_max(X, y):
+        with jax.named_scope(f"{prefix}/lambda_max"):
+            return lambda_max(X, y)
+
+    return jax.jit(scoped_lambda_max)
 
 
 def svm_path_scan(
@@ -942,7 +983,8 @@ def svm_path_scan(
     y = jnp.asarray(y)
     m, n = X.shape
 
-    lam_max_val = float(lambda_max(X, y))
+    with span("svm_path.lambda_max"):
+        lam_max_val = float(_lambda_max_program("svm_path")(X, y))
     if lambdas is None:
         lambdas = default_lambda_grid(lam_max_val, n_lambdas, lam_min_ratio)
     lambdas = _validate_grid(lambdas)
@@ -956,24 +998,23 @@ def svm_path_scan(
     static_kw = _static_opts(max_iters, screening, dynamic, screen_every,
                              use_pallas, exact_lipschitz, reduce, rules,
                              guards)
-    if dict(static_kw)["use_pallas"]:
-        from repro.kernels.ops import place_row_major  # lazy: no cycle
+    with span("svm_path.place_x"):
+        if dict(static_kw)["use_pallas"]:
+            from repro.kernels.ops import place_row_major  # lazy: no cycle
 
-        X = place_row_major(X)
+            X = place_row_major(X, scope="svm_path/place_x")
     engine = _engine_jit(static_kw, batched=None)
-    t0 = time.perf_counter()
-    outs = engine(X, y, jnp.asarray(lambdas, X.dtype), w0, b0, theta0,
-                  delta0, jnp.asarray(lam_max_val, X.dtype), None,
-                  float(tau), float(tol))
-    outs = jax.block_until_ready(outs)
-    t1 = time.perf_counter()
-    wall_s = t1 - t0
-    obs_trace.complete("scan.dispatch", t0, t1, steps=len(lambdas),
-                       reduce=dict(static_kw)["reduce"])
-    r = _to_path_result(lambdas, outs, lam_max_val, wall_s, screening,
-                        static_kw)
-    r.extras["path_trace"].emit_to_tracer()
-    return r
+    with span("svm_path.dispatch", steps=len(lambdas),
+              reduce=dict(static_kw)["reduce"]):
+        t0 = time.perf_counter()
+        outs = engine(X, y, jnp.asarray(lambdas, X.dtype), w0, b0, theta0,
+                      delta0, jnp.asarray(lam_max_val, X.dtype), None,
+                      float(tau), float(tol))
+        outs = jax.block_until_ready(outs)
+        wall_s = time.perf_counter() - t0
+    with span("svm_path.result"):
+        return _to_path_result(lambdas, outs, lam_max_val, wall_s,
+                               screening, static_kw)
 
 
 def svm_path_scan_sharded(
@@ -1052,7 +1093,8 @@ def svm_path_scan_sharded(
     sm = (None if n_pad == n else
           place(np.ones((n,), X.dtype), P(*data_axes), (n_pad,)))
 
-    lam_max_val = float(lambda_max(X, y, sm))
+    with span("svm_path_sharded.lambda_max"):
+        lam_max_val = float(lambda_max(X, y, sm))
     if lambdas is None:
         lambdas = default_lambda_grid(lam_max_val, n_lambdas, lam_min_ratio)
     lambdas = _validate_grid(lambdas)
@@ -1084,28 +1126,29 @@ def svm_path_scan_sharded(
         # replicated: the guard's trip verdict is pmax'd over the model axis
         # inside the body (solver._make_fista_body), so shards agree
         health=P(),
+        # replicated: the rounds' maxima are pmax'd over the model axis
+        feas_binding=P(),
     )
     fn = jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                                out_specs=out_specs, check_vma=False))
-    t0 = time.perf_counter()
-    outs = fn(X, y, jnp.asarray(lambdas, X.dtype), w0, b0, theta0, delta0,
-              jnp.asarray(lam_max_val, X.dtype),
-              jnp.asarray(float(tau), X.dtype),
-              jnp.asarray(float(tol), X.dtype),
-              *(() if sm is None else (sm,)))
-    outs = jax.block_until_ready(outs)
-    t1 = time.perf_counter()
-    wall_s = t1 - t0
-    obs_trace.complete("scan_sharded.dispatch", t0, t1, steps=len(lambdas))
-    if m_pad != m:
-        fmask = outs.fmask[:, :m]
-        outs = outs._replace(w=outs.w[:, :m], fmask=fmask, kept=jnp.sum(
-            fmask, axis=1).astype(jnp.int32))
-    r = _to_path_result(lambdas, outs, lam_max_val, wall_s, screening,
-                        static_kw, engine="scan_sharded")
+    with span("svm_path_sharded.dispatch", steps=len(lambdas)):
+        t0 = time.perf_counter()
+        outs = fn(X, y, jnp.asarray(lambdas, X.dtype), w0, b0, theta0,
+                  delta0, jnp.asarray(lam_max_val, X.dtype),
+                  jnp.asarray(float(tau), X.dtype),
+                  jnp.asarray(float(tol), X.dtype),
+                  *(() if sm is None else (sm,)))
+        outs = jax.block_until_ready(outs)
+        wall_s = time.perf_counter() - t0
+    with span("svm_path_sharded.result"):
+        if m_pad != m:
+            fmask = outs.fmask[:, :m]
+            outs = outs._replace(w=outs.w[:, :m], fmask=fmask, kept=jnp.sum(
+                fmask, axis=1).astype(jnp.int32))
+        r = _to_path_result(lambdas, outs, lam_max_val, wall_s, screening,
+                            static_kw, engine="scan_sharded")
     r.extras["engine"] = "scan_sharded"
     r.extras["mesh"] = dict(zip(mesh.axis_names, mesh.devices.shape))
-    r.extras["path_trace"].emit_to_tracer()
     return r
 
 
@@ -1173,7 +1216,8 @@ def svm_path_batched(
     if dict(static_kw)["use_pallas"]:
         from repro.kernels.ops import place_row_major  # lazy: no cycle
 
-        X = place_row_major(X)
+        with span("svm_path_batched.place_x"):
+            X = place_row_major(X, scope="svm_path_batched/place_x")
     if X.ndim == 2:
         # one problem, B grids — X/y/anchors stay unbatched (vmap broadcasts)
         if lambdas is None:
@@ -1187,7 +1231,8 @@ def svm_path_batched(
         for g in grids:
             _validate_grid(g)
         m = X.shape[0]
-        lam_max_val = float(lambda_max(X, y))
+        with span("svm_path_batched.lambda_max"):
+            lam_max_val = float(_lambda_max_program("svm_path_batched")(X, y))
         lam_maxs = np.full((B,), lam_max_val)
         engine = _engine_jit(
             static_kw, batched="grids_compact" if compact else "grids")
@@ -1201,7 +1246,8 @@ def svm_path_batched(
         B, m, _ = X.shape
         if y.ndim != 2 or y.shape[0] != B:
             raise ValueError(f"y must be (B, n) for 3-D X, got {y.shape}")
-        lam_maxs = np.asarray(jax.vmap(lambda_max)(X, y), np.float64)
+        with span("svm_path_batched.lambda_max"):
+            lam_maxs = np.asarray(jax.vmap(lambda_max)(X, y), np.float64)
         if lambdas is None:
             ratios = np.geomspace(1.0, lam_min_ratio, n_lambdas)
             grids = lam_maxs[:, None] * ratios[None, :]
@@ -1227,22 +1273,22 @@ def svm_path_batched(
         # the batched-compact program takes an optional per-element sample
         # mask right after (X, y) — unpadded callers pass None
         args = args[:2] + (None,) + args[2:]
-    t0 = time.perf_counter()
-    outs = engine(*args, None, float(tau), float(tol))
-    outs = jax.block_until_ready(outs)
-    t1 = time.perf_counter()
-    wall_s = t1 - t0
-    obs_trace.complete("batched.dispatch", t0, t1, batch=B)
+    with span("svm_path_batched.dispatch", batch=B):
+        t0 = time.perf_counter()
+        outs = engine(*args, None, float(tau), float(tol))
+        outs = jax.block_until_ready(outs)
+        wall_s = time.perf_counter() - t0
 
     results = []
-    for i in range(B):
-        sub = ScanPathOutputs(*(np.asarray(v)[i] for v in outs))
-        r = _to_path_result(grids[i], sub, float(lam_maxs[i]), wall_s / B,
-                            screening, static_kw, engine="batched")
-        r.extras["total_seconds"] = float(wall_s)
-        r.extras["batch"] = B
-        r.extras["batch_index"] = i
-        r.extras["path_trace"].meta["batch_index"] = i
-        r.extras["path_trace"].emit_to_tracer()
-        results.append(r)
+    with span("svm_path_batched.result", batch=B):
+        for i in range(B):
+            sub = ScanPathOutputs(*(np.asarray(v)[i] for v in outs))
+            r = _to_path_result(grids[i], sub, float(lam_maxs[i]),
+                                wall_s / B, screening, static_kw,
+                                engine="batched")
+            r.extras["total_seconds"] = float(wall_s)
+            r.extras["batch"] = B
+            r.extras["batch_index"] = i
+            r.extras["path_trace"].meta["batch_index"] = i
+            results.append(r)
     return results

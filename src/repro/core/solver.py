@@ -102,6 +102,7 @@ __all__ = [
     "fista_solve_dynamic",
     "fista_run",
     "gap_theta_delta",
+    "gap_theta_delta_binding",
 ]
 
 
@@ -617,6 +618,14 @@ def fista_solve(
                             _resolve_guards(guards))
 
 
+#: A feasibility round of :func:`gap_theta_delta_binding` counts as binding
+#: when its max exceeds ``lam`` by more than this share. A round right after
+#: a rescale recomputes a max of ``lam`` itself, give or take the rounding
+#: of its sweep: in float32 that reads one or two ulps above ``lam`` as often
+#: as below, which a plain ``max > lam`` would count.
+FEAS_BINDING_RTOL = 1e-5
+
+
 def gap_theta_delta(
     X: jax.Array,
     y: jax.Array,
@@ -630,6 +639,27 @@ def gap_theta_delta(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Gap-certified ``(theta1, delta, gap)`` at the current iterate.
 
+    :func:`gap_theta_delta_binding` with its count of binding rounds left
+    out; see there.
+    """
+    return gap_theta_delta_binding(X, y, w, b, lam, sample_mask,
+                                   n_feas_iters, col, u)[:3]
+
+
+def gap_theta_delta_binding(
+    X: jax.Array,
+    y: jax.Array,
+    w: jax.Array,
+    b: jax.Array,
+    lam: jax.Array,
+    sample_mask: Optional[jax.Array] = None,
+    n_feas_iters: int = 4,
+    col: Collectives = LOCAL,
+    u: Optional[jax.Array] = None,
+    scope: str = "feasibility",
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Gap-certified ``(theta1, delta, gap, binding)`` at the current iterate.
+
     The sample-masked generalization of ``dual.safe_theta_and_delta`` (same
     alternating feasibility projection, same 1-strong-concavity radius):
     with a 0/1 ``sample_mask`` the problem being certified is the one with
@@ -641,6 +671,14 @@ def gap_theta_delta(
     already carries them for its accepted point, so certifying a just-solved
     iterate saves one full sweep of X. ``col`` binds the reductions to mesh
     collectives for ``shard_map`` blocks (see :class:`Collectives`).
+
+    ``binding`` (int32) counts the feasibility rounds, the final rescale
+    included (``n_feas_iters + 1`` in all), whose ``max_j |X_j^T (y*alpha)|``
+    exceeded ``lam`` by more than :data:`FEAS_BINDING_RTOL`, so that their
+    rescale moved alpha by more than the rounding of the max's own sweep.
+    It is read from the maxima the rounds compute anyway: no extra sweep.
+    The rounds run in the name scope ``scope``: under ``vmap`` the name
+    stack reads ``vmap(<scope>)``, so a batched caller passes its full path.
     """
     sm = sample_mask
     if u is None:
@@ -657,21 +695,29 @@ def gap_theta_delta(
         n_eff = col.psum_data(jnp.asarray(float(y.shape[0]), X.dtype))
 
     def corr_scale(alpha):
+        """``(scale, binding)``: the rescale of alpha onto the feasible
+        box, and whether it moves alpha (the max exceeds ``lam``)."""
         corr = col.psum_data(dot(X, y * alpha))  # fhat_j^T alpha for all j
         mx = col.pmax_model(jnp.max(jnp.abs(corr)))
-        return jnp.minimum(1.0, lam / jnp.maximum(mx, 1e-30))
+        return (jnp.minimum(1.0, lam / jnp.maximum(mx, 1e-30)),
+                mx > lam * (1.0 + FEAS_BINDING_RTOL))
 
     def body(alpha, _):
-        alpha = alpha * corr_scale(alpha)
+        scale, binding = corr_scale(alpha)
+        alpha = alpha * scale
         alpha = jnp.maximum(
             0.0, alpha - col.psum_data(dot(alpha, y)) / n_eff * y)
         if sm is not None:
             alpha = alpha * sm
-        return alpha, None
+        return alpha, binding
 
-    alpha, _ = jax.lax.scan(body, alpha, None, length=n_feas_iters)
-    # final rescale so the inequality constraints hold for sure
-    alpha = alpha * corr_scale(alpha)
+    with jax.named_scope(scope):
+        alpha, bound = jax.lax.scan(body, alpha, None, length=n_feas_iters)
+        # final rescale so the inequality constraints hold for sure
+        scale, last_bound = corr_scale(alpha)
+        alpha = alpha * scale
+    binding = (jnp.sum(bound.astype(jnp.int32))
+               + last_bound.astype(jnp.int32))
     d_obj = col.psum_data(jnp.sum(alpha)) - 0.5 * col.psum_data(
         jnp.sum(alpha * alpha))
     gap = jnp.maximum(p_obj - d_obj, 0.0)
@@ -690,7 +736,8 @@ def gap_theta_delta(
     cert_ok = (jnp.isfinite(gap) & jnp.isfinite(delta)
                & jnp.all(jnp.isfinite(theta)))
     inf = jnp.asarray(jnp.inf, X.dtype)
-    return theta, jnp.where(cert_ok, delta, inf), jnp.where(cert_ok, gap, inf)
+    return (theta, jnp.where(cert_ok, delta, inf),
+            jnp.where(cert_ok, gap, inf), binding)
 
 
 def _dynamic_run(

@@ -104,6 +104,7 @@ def hinge_margin_pallas(
 
     u, xi, loss_parts = pl.pallas_call(
         _margin_kernel,
+        name="hinge_margin_pallas",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -180,6 +181,7 @@ def hinge_grad_pallas(
 
     return pl.pallas_call(
         _grad_kernel,
+        name="hinge_grad_pallas",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

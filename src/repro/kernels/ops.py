@@ -8,7 +8,7 @@ packing of the feature-independent scalars.
 from __future__ import annotations
 
 import os
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -81,13 +81,15 @@ def kernel_operand(X: jax.Array, block_m: int = 256,
     return _pad_to(_pad_to(X, block_m, 0), block_n, 1)
 
 
-def place_row_major(X):
+def place_row_major(X, scope: str):
     """``X`` on the device in row-major tiled layout, on a TPU.
 
     The default device layout of an (m, n) array whose n is not a multiple
     of 128 can be column-major; a program that hands it to the kernels then
     keeps a row-major copy of all of X. Path engines that run the Pallas
     sweeps place X with this once, before dispatch (off a TPU: unchanged).
+    The copy is one jitted program whose device op carries the name scope
+    ``scope`` (``svm_path/place_x``).
     """
     if not _on_tpu():
         return X
@@ -98,7 +100,20 @@ def place_row_major(X):
     if sharding is None or len(sharding.device_set) != 1:
         sharding = SingleDeviceSharding(jax.devices()[0])
     fmt = Format(Layout(major_to_minor=tuple(range(np.ndim(X)))), sharding)
-    return jax.device_put(X, fmt)
+    return _relayout_program(fmt, scope)(X)
+
+
+@lru_cache(maxsize=None)
+def _relayout_program(fmt, scope: str):
+    """A jitted copy of its argument into ``fmt``. The barrier keeps an
+    instruction under ``scope`` in the program, and the layout copy XLA
+    inserts after it takes its name (an identity would give the copy the
+    parameter's name, outside any scope)."""
+    def place_row_major_copy(X):
+        with jax.named_scope(scope):
+            return jax.lax.optimization_barrier(X)
+
+    return jax.jit(place_row_major_copy, out_shardings=fmt)
 
 
 def screen_bounds_op(

@@ -243,6 +243,7 @@ def screen_bounds_pallas(
         grid = (m // block_m, n // block_n)
         return pl.pallas_call(
             _feature_kernel,
+            name="screen_bounds_pallas",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_n), lambda i, j: (i, j)),
@@ -260,6 +261,7 @@ def screen_bounds_pallas(
         grid = (n // block_n, m // block_m)
         return pl.pallas_call(
             _sample_kernel,
+            name="screen_bounds_pallas",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((block_m, block_n), lambda i, j: (j, i)),

@@ -388,10 +388,11 @@ class PathServer:
         act = jnp.asarray(self._act)
         tau = jnp.asarray(self.tau, self.dtype)
         carry_prev = self._carry  # functional updates: free pre-step copy
-        self._carry, out = fn(self._X, self._y, self._sm, self._statics,
-                              self._inv_L, tau, self.tol, carry_prev,
-                              lam, act)
-        host = {k: np.asarray(v) for k, v in out._asdict().items()}
+        with obs_trace.span("serve.dispatch", slots=self.slots):
+            self._carry, out = fn(self._X, self._y, self._sm, self._statics,
+                                  self._inv_L, tau, self.tol, carry_prev,
+                                  lam, act)
+            host = {k: np.asarray(v) for k, v in out._asdict().items()}
         self._bump("steps")
         self._bump("occupied_slots", int(self._act.sum()))
         if self.reduce == "compact" and int(host["cap"][0]) >= m_b:
@@ -475,9 +476,10 @@ class PathServer:
         stacked["cap"] = np.minimum(stacked["cap"], m)
         outs = ScanPathOutputs(**stacked)
         latency = job.t_done - job.t_submit
-        r = _to_path_result(job.lambdas, outs, job.lam_max,
-                            latency, job.screening,
-                            self._cfg, engine="serve")
+        with obs_trace.span("serve.result", jid=job.jid):
+            r = _to_path_result(job.lambdas, outs, job.lam_max,
+                                latency, job.screening,
+                                self._cfg, engine="serve")
         r.extras["engine"] = "serve"
         r.extras["jid"] = job.jid
         r.extras["latency_s"] = latency
@@ -487,7 +489,6 @@ class PathServer:
         pt = r.extras["path_trace"]
         pt.meta["jid"] = job.jid
         pt.meta["latency_s"] = float(latency)
-        pt.emit_to_tracer()
         job.result = r
         return r
 

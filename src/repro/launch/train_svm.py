@@ -49,6 +49,7 @@ import numpy as np
 
 from repro.compile_cache import enable_compile_cache
 from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 from repro.obs.log import get_logger, setup as log_setup
 
 _LOG = get_logger("launch.train_svm")
@@ -218,78 +219,79 @@ def run_path(
     results = []
     for k in range(start_k, len(lambdas)):
         lam1, lam2 = float(lambdas[k - 1]), float(lambdas[k])
-        t0 = time.perf_counter()
+        with span("path.step", step=k, lam=lam2) as sp:
+            t0 = time.perf_counter()
 
-        region = ConvexRegion.build(
-            yj, lam1, lam2, state["theta"], delta=state["delta"],
-            w1=state["w"], b1=float(state["b"]),
-            dw=float(state["dw"]), db=float(state["db"]),
-        )
-        keep = jnp.ones((m,), bool)
-        for rule in sharded_feature:
-            # state["delta"] bounds ||theta - theta*(lam1)|| for the
-            # sequentially-solved anchor; without it the sharded screen
-            # would assume theta exact and could unsafely reject features
-            k_mask, _ = screen_sharded(mesh, Xj, yj, lam1, lam2,
-                                       state["theta"], tau=rule.tau,
-                                       delta=state["delta"])
-            keep = keep & k_mask
-        for rule in generic_feature:
-            keep = keep & jnp.asarray(rule.keep(rule.bounds(Xj, yj, region)))
-        s_mask = np.ones((n,), dtype=bool)
-        for rule in sharded_sample:
-            # the mesh-parallel margin sweep (ROADMAP: queued since PR 4):
-            # same two feature-axis reductions, psum over "model", and the
-            # rule's own slack arithmetic — bitwise the local oracle on
-            # meshes that keep the feature axis whole. The secant history
-            # lives on the rule object, exactly as in the local path.
-            surplus, u1 = sample_surplus_sharded(
-                mesh, Xj, yj, state["w"], float(state["b"]),
+            region = ConvexRegion.build(
+                yj, lam1, lam2, state["theta"], delta=state["delta"],
+                w1=state["w"], b1=float(state["b"]),
                 dw=float(state["dw"]), db=float(state["db"]),
-                u_prev=rule._u_prev, shrink_factor=rule.shrink_factor,
-                margin_floor=rule.margin_floor,
             )
-            rule._u_prev = u1
-            s_mask &= np.asarray(surplus < 0.0)
-        for rule in generic_sample:
-            s_mask &= np.asarray(rule.keep(rule.bounds(Xj, yj, region)))
+            keep = jnp.ones((m,), bool)
+            for rule in sharded_feature:
+                # state["delta"] bounds ||theta - theta*(lam1)|| for the
+                # sequentially-solved anchor; without it the sharded screen
+                # would assume theta exact and could unsafely reject features
+                k_mask, _ = screen_sharded(mesh, Xj, yj, lam1, lam2,
+                                           state["theta"], tau=rule.tau,
+                                           delta=state["delta"])
+                keep = keep & k_mask
+            for rule in generic_feature:
+                keep = keep & jnp.asarray(
+                    rule.keep(rule.bounds(Xj, yj, region)))
+            s_mask = np.ones((n,), dtype=bool)
+            for rule in sharded_sample:
+                # the mesh-parallel margin sweep (ROADMAP: queued since PR 4):
+                # same two feature-axis reductions, psum over "model", and the
+                # rule's own slack arithmetic — bitwise the local oracle on
+                # meshes that keep the feature axis whole. The secant history
+                # lives on the rule object, exactly as in the local path.
+                surplus, u1 = sample_surplus_sharded(
+                    mesh, Xj, yj, state["w"], float(state["b"]),
+                    dw=float(state["dw"]), db=float(state["db"]),
+                    u_prev=rule._u_prev, shrink_factor=rule.shrink_factor,
+                    margin_floor=rule.margin_floor,
+                )
+                rule._u_prev = u1
+                s_mask &= np.asarray(surplus < 0.0)
+            for rule in generic_sample:
+                s_mask &= np.asarray(rule.keep(rule.bounds(Xj, yj, region)))
 
-        kept = int(jnp.sum(keep))
-        # mask-mode reduction keeps static shapes across the sharded solve
-        Xr = Xj * keep[:, None].astype(Xj.dtype)
-        warm = {"w": state["w"] * keep, "b": state["b"]}
+            kept = int(jnp.sum(keep))
+            # mask-mode reduction keeps static shapes across the sharded solve
+            Xr = Xj * keep[:, None].astype(Xj.dtype)
+            warm = {"w": state["w"] * keep, "b": state["b"]}
 
-        def solve(mask):
-            # the dynamic segmented solve keeps tightening the feature mask
-            # in-loop, seeded from the between-lambda sequential screen
-            r = fista_sharded(
-                mesh, Xr, yj, lam2, max_iters=max_iters, tol=tol,
-                w0=warm["w"], b0=warm["b"],
-                sample_mask=jnp.asarray(mask, jnp.float32),
-                feature_mask=keep.astype(jnp.float32),
-                screen_every=screen_every if dynamic else None,
-                tau=dynamic_tau(feature_rules),
-                L=L_path,
+            def solve(mask):
+                # the dynamic segmented solve keeps tightening the feature mask
+                # in-loop, seeded from the between-lambda sequential screen
+                r = fista_sharded(
+                    mesh, Xr, yj, lam2, max_iters=max_iters, tol=tol,
+                    w0=warm["w"], b0=warm["b"],
+                    sample_mask=jnp.asarray(mask, jnp.float32),
+                    feature_mask=keep.astype(jnp.float32),
+                    screen_every=screen_every if dynamic else None,
+                    tau=dynamic_tau(feature_rules),
+                    L=L_path,
+                )
+                warm["w"], warm["b"] = r.w, r.b
+                return r, np.asarray(r.w, np.float64), float(r.b)
+
+            res, _, _, rounds = solve_with_verification(
+                solve, sample_rules, X_np, y_np, s_mask,
+                max_rounds=max_verify_rounds,
             )
-            warm["w"], warm["b"] = r.w, r.b
-            return r, np.asarray(r.w, np.float64), float(r.b)
 
-        res, _, _, rounds = solve_with_verification(
-            solve, sample_rules, X_np, y_np, s_mask,
-            max_rounds=max_verify_rounds,
-        )
-
-        dw_obs = float(jnp.linalg.norm(res.w - state["w"]))
-        db_obs = abs(float(res.b) - float(state["b"]))
-        theta, delta = safe_theta_and_delta(Xj, yj, res.w, res.b,
-                                            jnp.asarray(lam2))
-        state = {"w": res.w, "b": res.b, "theta": theta, "delta": delta,
-                 "dw": jnp.asarray(shrink_factor * dw_obs, jnp.float32),
-                 "db": jnp.asarray(shrink_factor * db_obs, jnp.float32),
-                 "k": jnp.asarray(k, jnp.int32)}
-        dt = time.perf_counter() - t0
-        obs_trace.complete("path.step", t0, t0 + dt, step=k, lam=lam2,
-                           kept=kept, iters=int(res.n_iters))
+            dw_obs = float(jnp.linalg.norm(res.w - state["w"]))
+            db_obs = abs(float(res.b) - float(state["b"]))
+            theta, delta = safe_theta_and_delta(Xj, yj, res.w, res.b,
+                                                jnp.asarray(lam2))
+            state = {"w": res.w, "b": res.b, "theta": theta, "delta": delta,
+                     "dw": jnp.asarray(shrink_factor * dw_obs, jnp.float32),
+                     "db": jnp.asarray(shrink_factor * db_obs, jnp.float32),
+                     "k": jnp.asarray(k, jnp.int32)}
+            dt = time.perf_counter() - t0
+            sp.set(kept=kept, iters=int(res.n_iters))
         nnz = int(jnp.sum(jnp.abs(res.w) > 1e-8))
         kept_n = int(s_mask.sum())
         row = {"lam": lam2, "kept": kept, "kept_samples": kept_n,
@@ -466,15 +468,18 @@ def main():
     ap.add_argument("--serve-jobs", type=int, default=8)
     ap.add_argument("--serve-slots", type=int, default=4)
     ap.add_argument("--trace", default=None, metavar="FILE",
-                    help="record obs spans around the path and export "
-                         "Chrome trace-event JSON here (open in Perfetto / "
-                         "chrome://tracing); equivalent to REPRO_TRACE=1 "
-                         "plus an export")
+                    help="record the program's spans on the host and "
+                         "export them as Chrome trace-event JSON here "
+                         "(open in Perfetto / chrome://tracing; no device "
+                         "ops); equivalent to REPRO_TRACE=1 plus an export")
     ap.add_argument("--profile", default=None, metavar="DIR",
-                    help="capture a jax.profiler device trace of the path "
-                         "into DIR (view with TensorBoard / Perfetto; the "
-                         "engines' named_scope annotations label the "
-                         "regions)")
+                    help="capture a jax.profiler trace of the path into "
+                         "DIR (view with TensorBoard / Perfetto): the "
+                         "program's host spans (svm_path, "
+                         "svm_path.dispatch, ...) and its device ops under "
+                         "their named scopes (svm_path/prologue, "
+                         "svm_path/certify/feasibility, ...) on one "
+                         "timeline")
     args = ap.parse_args()
 
     log_setup()

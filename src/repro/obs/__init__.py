@@ -4,19 +4,23 @@ The repo runs the screened SVM path through six engines (host, scan,
 batched, sharded, server, chunked/mmap); this package is the one
 instrumentation layer they all report through:
 
-- :mod:`repro.obs.trace` — a low-overhead span recorder
-  (``span("solve", step=k)`` context manager + instant events, no-op when
-  disabled, thread-safe for the server drain loop) exporting Chrome
-  trace-event JSON loadable in Perfetto. Threaded through
-  ``PathDriver.run``, the streamed solver's host loop,
-  ``screen_step_stream``, and the ``PathServer`` drain/refill/checkpoint
-  cycle. Enable with ``REPRO_TRACE=1`` or ``train_svm --trace out.json``.
+- :mod:`repro.obs.trace` — the one span primitive
+  (``span("svm_path.dispatch")`` context manager, thread-safe for the
+  server drain loop). Every span is a ``jax.profiler.TraceAnnotation``, so
+  under ``jax.profiler`` (``train_svm --profile DIR``) the program's host
+  spans share one timeline with the device ops; a recorder, off by
+  default, also exports them as Chrome trace-event JSON loadable in
+  Perfetto (``REPRO_TRACE=1`` or ``train_svm --trace out.json``). Threaded
+  through the entry (``svm_path`` and its phases), ``PathDriver.run``, the
+  streamed solver's host loop, ``screen_step_stream``, and the
+  ``PathServer`` drain/refill/checkpoint cycle.
 - :mod:`repro.obs.metrics` — a process-wide registry of counters /
   gauges / histograms absorbing the previously scattered telemetry
   (engine-cache hit/miss/retrace, ``chunks_streamed`` /
   ``chunks_skipped`` / ``bytes_put``, guard trips, kept-per-step, job
-  latency) with JSON and Prometheus-text dumps; ``PathServer.metrics()``
-  returns its snapshot.
+  latency, the entry's host time per call, the certificate's binding
+  share per path) with JSON and Prometheus-text dumps;
+  ``PathServer.metrics()`` returns its snapshot.
 - :mod:`repro.obs.log` — structured-logging setup (module-level loggers,
   one handler on the ``repro`` root, ``REPRO_LOG_LEVEL`` env-tunable).
 - :mod:`repro.obs.path_trace` — the uniform ``PathTrace`` artifact every
@@ -66,7 +70,6 @@ from .metrics import (
 from .path_trace import PathStep, PathTrace, build_path_trace
 from .trace import (
     Tracer,
-    complete,
     enable,
     enabled,
     disable,
@@ -92,7 +95,6 @@ __all__ = [
     "PathTrace",
     "build_path_trace",
     "Tracer",
-    "complete",
     "enable",
     "enabled",
     "disable",

@@ -15,7 +15,9 @@ Conventions: dotted lowercase names namespaced by subsystem —
 ``serve.hits``, ``stream.chunks_skipped``, ``path.guard_trips``,
 ``engine.cache.retraces`` — with counters for monotonic totals, gauges for
 last-observed values, histograms for per-event distributions
-(``serve.latency_s``, ``path.kept``). Prometheus output maps dots to
+(``serve.latency_s``, ``path.kept``, and one observation per call in
+``path.entry_s`` and ``path.certify_binding_share``, whose ``last`` the
+benchmark reads). Prometheus output maps dots to
 underscores (``repro_serve_hits_total``).
 
 Thread-safe: metric creation and increments take the registry/metric lock
@@ -98,16 +100,18 @@ class Gauge:
 
 
 class Histogram:
-    """Streaming distribution summary: count / sum / min / max.
+    """Streaming distribution summary: count / sum / min / max / last.
 
     Deliberately bucket-free — the consumers here (bench deltas, serve
     latency percentiles over small job counts) keep the raw observations
     when they need quantiles; the registry's job is the cheap always-on
-    aggregate.
+    aggregate. ``last`` is the newest observation: the value of the last
+    call, where a histogram takes one observation per call
+    (``path.entry_s``).
     """
 
     kind = "histogram"
-    __slots__ = ("name", "count", "total", "min", "max", "_lock")
+    __slots__ = ("name", "count", "total", "min", "max", "last", "_lock")
 
     def __init__(self, name: str):
         self.name = name
@@ -116,12 +120,14 @@ class Histogram:
         self.total = 0.0
         self.min = float("inf")
         self.max = float("-inf")
+        self.last = None
 
     def observe(self, v):
         v = float(v)
         with self._lock:
             self.count += 1
             self.total += v
+            self.last = v
             if v < self.min:
                 self.min = v
             if v > self.max:
@@ -131,10 +137,10 @@ class Histogram:
         with self._lock:
             if self.count == 0:
                 return {"count": 0, "sum": 0.0, "min": None, "max": None,
-                        "mean": None}
+                        "mean": None, "last": None}
             return {"count": self.count, "sum": self.total,
                     "min": self.min, "max": self.max,
-                    "mean": self.total / self.count}
+                    "mean": self.total / self.count, "last": self.last}
 
     def reset(self):
         with self._lock:
@@ -142,6 +148,7 @@ class Histogram:
             self.total = 0.0
             self.min = float("inf")
             self.max = float("-inf")
+            self.last = None
 
 
 class MetricsRegistry:
@@ -217,6 +224,9 @@ class MetricsRegistry:
                 lines.append(f"# TYPE {base} summary")
                 lines.append(f"{base}_count {s['count']}")
                 lines.append(f"{base}_sum {s['sum']}")
+                if s["last"] is not None:
+                    lines.append(f"# TYPE {base}_last gauge")
+                    lines.append(f"{base}_last {s['last']}")
         return "\n".join(lines) + "\n"
 
 

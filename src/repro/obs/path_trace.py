@@ -3,8 +3,9 @@
 Every path engine (host / scan / batched / sharded / serve / chunked)
 attaches one ``PathTrace`` to its result (``PathResult.extras
 ["path_trace"]``): the same schema of per-step records regardless of how
-the engine executes, so bench comparisons, the trace exporter, and the
-profiler lane read ONE shape instead of five engine-specific dicts.
+the engine executes, so readers of a result see ONE shape instead of five
+engine-specific dicts. It is a record of counts and walls, not a trace:
+the spans on a timeline are the engines' own (``repro.obs.trace.span``).
 
 Host-orchestrated engines fill the records live (each step's walls are
 measured on the host); single-dispatch engines (scan/batched/sharded and
@@ -20,12 +21,9 @@ See :class:`PathStep` for the field reference (also reproduced in the
 
 from __future__ import annotations
 
-import math
 from dataclasses import asdict, dataclass, field
 
 import numpy as np
-
-from . import trace as _trace
 
 __all__ = ["PathStep", "PathTrace", "build_path_trace"]
 
@@ -96,51 +94,6 @@ class PathTrace:
             "meta": dict(self.meta),
             "steps": [asdict(s) for s in self.steps],
         }
-
-    # -- trace synthesis ---------------------------------------------------
-
-    def to_chrome_events(self, end_s: float, tid: int = 0) -> list:
-        """Complete ('X') trace events laying the steps out on a timeline
-        ending at ``end_s`` (seconds relative to the consumer's epoch) —
-        the post-hoc span synthesis for engines with no live host loop.
-        Phase walls, when observed, become child events nested inside each
-        step's interval."""
-        walls = [s.wall_s for s in self.steps]
-        start = end_s - sum(walls)
-        events = []
-        t = start
-        for s in self.steps:
-            args = {"lam": s.lam, "kept": s.kept, "active": s.active,
-                    "iters": s.iters, "health": s.health}
-            if not math.isnan(s.gap):
-                args["gap"] = s.gap
-            events.append({
-                "name": f"{self.engine}.step", "ph": "X",
-                "ts": t * 1e6, "dur": s.wall_s * 1e6,
-                "tid": tid, "args": args,
-            })
-            tp = t
-            for phase in ("screen", "solve", "certify"):
-                dur = getattr(s, f"{phase}_s")
-                if not math.isnan(dur):
-                    events.append({
-                        "name": f"{self.engine}.{phase}", "ph": "X",
-                        "ts": tp * 1e6, "dur": dur * 1e6,
-                        "tid": tid, "args": {"step": s.step},
-                    })
-                    tp += dur
-            t += s.wall_s
-        return events
-
-    def emit_to_tracer(self, tracer=None):
-        """Append this trace's synthesized spans to the (enabled) process
-        tracer so ``--trace out.json`` exports contain per-step spans from
-        every engine, live-recorded or not."""
-        tracer = tracer or _trace.get_tracer()
-        if not tracer.enabled:
-            return
-        for ev in self.to_chrome_events(end_s=tracer.now()):
-            tracer._append(ev)
 
 
 def _col(x, k, default=NAN):

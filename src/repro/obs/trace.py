@@ -1,28 +1,27 @@
-"""Low-overhead span recorder exporting Chrome trace-event JSON.
+"""Program spans on the profiler's clock, and a Chrome trace-event recorder.
 
-One process-wide :class:`Tracer` (disabled by default) records *spans* —
-named, attributed intervals — and *instant events*. The recorder is built
-for the repo's host loops (``PathDriver.run``, the streamed solver,
-``PathServer``'s drain loop): when disabled, :func:`span` returns a shared
-no-op singleton and records nothing (no event allocation, no lock, no
-clock read beyond the enabled check), so instrumentation can stay in the
-hot path permanently. When enabled, every span costs two
-``perf_counter`` reads and one locked list append — thread-safe, so the
-server drain loop and any worker threads interleave correctly (events
-carry the recording thread's id).
+:func:`span` is the one span primitive of the repo. Every span enters a
+``jax.profiler.TraceAnnotation`` of its name, always: under a running
+``jax.profiler`` session (``train_svm --profile DIR``, the benchmark's
+traced runs) the program's host spans land on the profiler's host plane,
+on the same clock as the device ops and their ``jax.named_scope`` regions.
+With no profiler session an annotation costs about a microsecond.
 
-Export is the Chrome trace-event format (``{"traceEvents": [...]}``),
-loadable in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``:
-spans become complete events (``ph="X"``, microsecond ``ts``/``dur``),
-instants become ``ph="i"``, and span attributes ride ``args``.
+Beside that, one process-wide :class:`Tracer` (off by default) records
+the spans and *instant events* itself and exports them as Chrome
+trace-event JSON (``{"traceEvents": [...]}``), loadable in Perfetto
+(https://ui.perfetto.dev) or ``chrome://tracing``: spans become complete
+events (``ph="X"``, microsecond ``ts``/``dur`` on the recorder's own
+``perf_counter`` epoch), instants become ``ph="i"``, and span attributes
+ride ``args``. When the recorder is on, a span costs two more
+``perf_counter`` reads and one locked list append; it is thread-safe, so
+the server drain loop and worker threads interleave correctly (events
+carry the recording thread's id). When it is off, a span records nothing
+in it.
 
-Enable programmatically (:func:`enable`) or via ``REPRO_TRACE=1`` in the
-environment; ``train_svm --trace out.json`` wires both ends together.
-
-Single-dispatch engines (scan/batched/sharded/serve) cannot record live
-per-step spans — their steps run inside one jitted program. They
-synthesize spans post-hoc from device telemetry instead: see
-``repro.obs.path_trace.PathTrace.emit_to_tracer``.
+Enable the recorder programmatically (:func:`enable`) or via
+``REPRO_TRACE=1`` in the environment; ``train_svm --trace out.json`` wires
+both ends together.
 """
 
 from __future__ import annotations
@@ -33,11 +32,12 @@ import threading
 import time
 from pathlib import Path
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Tracer",
     "span",
     "instant",
-    "complete",
     "enable",
     "disable",
     "enabled",
@@ -46,28 +46,13 @@ __all__ = [
 ]
 
 
-class _NoopSpan:
-    """Shared do-nothing span: the disabled-mode fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs):
-        return self
-
-
-NOOP_SPAN = _NoopSpan()
-
-
 class _Span:
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    """A named interval: a profiler annotation always, and a recorded
+    event when its recorder was on at the span's creation."""
 
-    def __init__(self, tracer: "Tracer", name: str, attrs: dict):
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_ann")
+
+    def __init__(self, tracer: "Tracer | None", name: str, attrs: dict):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
@@ -79,12 +64,18 @@ class _Span:
         return self
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
-        self._tracer._record(self.name, self._t0, t1 - self._t0, self.attrs)
+        if self._tracer is not None:
+            t1 = time.perf_counter()
+            self._tracer._record(self.name, self._t0, t1 - self._t0,
+                                 self.attrs)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -132,10 +123,9 @@ class Tracer:
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, **attrs):
-        """Context manager timing a named interval; no-op when disabled."""
-        if not self._enabled:
-            return NOOP_SPAN
-        return _Span(self, name, attrs)
+        """Context manager timing a named interval; recorded only when the
+        recorder is on, annotated for the profiler always."""
+        return _Span(self if self._enabled else None, name, attrs)
 
     def instant(self, name: str, **attrs):
         """Record a zero-duration marker event; no-op when disabled."""
@@ -155,19 +145,6 @@ class Tracer:
             "dur": dur_s * 1e6,
             "tid": threading.get_ident(),
             "args": attrs,
-        })
-
-    def add_complete_event(self, name: str, start_s: float, dur_s: float,
-                           tid: int = 0, **attrs):
-        """Append a complete ('X') event with explicit relative timing —
-        the post-hoc synthesis path for single-dispatch engines (timestamps
-        in seconds since the tracer epoch)."""
-        if not self._enabled:
-            return
-        self._append({
-            "name": name, "ph": "X",
-            "ts": start_s * 1e6, "dur": dur_s * 1e6,
-            "tid": tid, "args": attrs,
         })
 
     def _append(self, ev: dict):
@@ -220,29 +197,11 @@ def disable():
 def span(name: str, **attrs):
     """Module-level ``with span("solve", step=k): ...`` on the process
     tracer — the form the engines thread through their hot loops."""
-    if not _TRACER._enabled:
-        return NOOP_SPAN
-    return _Span(_TRACER, name, attrs)
+    return _TRACER.span(name, **attrs)
 
 
 def instant(name: str, **attrs):
     _TRACER.instant(name, **attrs)
-
-
-def complete(name: str, t0: float, t1: float, **attrs):
-    """Record a complete span from absolute ``perf_counter`` stamps the
-    caller already took for its own bookkeeping (the host path loops stamp
-    screen/solve/certify walls regardless of tracing) — zero extra clock
-    reads, no-op when disabled."""
-    if not _TRACER._enabled:
-        return
-    _TRACER._append({
-        "name": name, "ph": "X",
-        "ts": (t0 - _TRACER._epoch) * 1e6,
-        "dur": (t1 - t0) * 1e6,
-        "tid": threading.get_ident(),
-        "args": attrs,
-    })
 
 
 def export_chrome(path) -> str:

@@ -49,7 +49,6 @@ testable property rather than a hope.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Optional
 
 import jax
@@ -57,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 from repro.core.rules.programs import PROGRAMS, stack_bounds
 from repro.core.screening import (
     SAFE_TAU,
@@ -405,60 +404,60 @@ def screen_step_stream(
     """
     from repro.kernels.ops import fista_use_pallas  # lazy: no import cycle
 
-    _tt0 = time.perf_counter()
-    y_key = y
-    d_one, d_y, d_sq = fixed_reductions(fc, y)
-    y = jnp.asarray(y, fc.dtype)
-    theta1 = jnp.asarray(theta1, fc.dtype)
-    fixed = fixed_stats(y, d_one, d_y, d_sq)
+    with span("stream.screen", chunks=int(fc.n_chunks),
+              skip=bool(skip)) as sp:
+        y_key = y
+        d_one, d_y, d_sq = fixed_reductions(fc, y)
+        y = jnp.asarray(y, fc.dtype)
+        theta1 = jnp.asarray(theta1, fc.dtype)
+        fixed = fixed_stats(y, d_one, d_y, d_sq)
 
-    if cache is None:
-        cache = ChunkScreenCache(fc)
-    needs_hist = (anchor_old is not None
-                  or any(PROGRAMS[nm].n_anchors > 1 for nm in rules))
-    if needs_hist:
-        live = np.ones((fc.n_chunks,), dtype=bool)
-        stale_bounds = None
-    else:
-        live, stale_bounds = cache.live_mask(lam2, fixed, tau)
-    live_arg = None if bool(live.all()) else live
-
-    pure_vi = tuple(rules) == ("feature_vi",) and anchor_old is None
-    if pure_vi and fista_use_pallas(use_pallas):
-        anchor, bounds = _pallas_step(fc, y_key, y, lam1, lam2, theta1,
-                                      delta, cache, live, skip)
-    else:
-        anchor = stream_anchor_stats(
-            fc, y_key, lam1, theta1, delta=delta,
-            live_chunks=live_arg if skip else None,
-            cache=cache if skip else None)
-        if not skip:
-            # full-stream twin: the transfer happened for every chunk, but
-            # cache entries for dead chunks must NOT advance — identical
-            # cache evolution to the skipping run is what makes the two
-            # modes bitwise-comparable — so refresh the live set only.
-            cache.refresh(anchor,
-                          live=set(int(i) for i in np.nonzero(live)[0]))
-        if pure_vi:
-            red = FeatureReductions(d_theta=anchor.d_theta, d_one=d_one,
-                                    d_y=d_y, d_sq=d_sq)
-            sh = shared_scalars(y, lam1, lam2, theta1, delta=delta)
-            bounds = _finalize_bounds(red, sh)
+        if cache is None:
+            cache = ChunkScreenCache(fc)
+        needs_hist = (anchor_old is not None
+                      or any(PROGRAMS[nm].n_anchors > 1 for nm in rules))
+        if needs_hist:
+            live = np.ones((fc.n_chunks,), dtype=bool)
+            stale_bounds = None
         else:
-            anchors = (anchor,) if anchor_old is None else (anchor_old, anchor)
-            progs = tuple(PROGRAMS[nm] for nm in rules)
-            bounds = stack_bounds(progs, lam2, anchors, fixed)
+            live, stale_bounds = cache.live_mask(lam2, fixed, tau)
+        live_arg = None if bool(live.all()) else live
 
-    if not bool(live.all()):
-        dead_feat = np.repeat(
-            ~live, np.diff(fc.offsets).astype(np.int64))
-        bounds = jnp.where(jnp.asarray(dead_feat), stale_bounds, bounds)
-    # NaN-safe keep: a non-finite bound certifies nothing — keep the feature
-    keep = ~(bounds < tau)
-    if obs_trace.enabled():
-        obs_trace.complete("stream.screen", _tt0, time.perf_counter(),
-                           live=int(np.count_nonzero(live)),
-                           chunks=int(fc.n_chunks), skip=bool(skip))
+        pure_vi = tuple(rules) == ("feature_vi",) and anchor_old is None
+        if pure_vi and fista_use_pallas(use_pallas):
+            anchor, bounds = _pallas_step(fc, y_key, y, lam1, lam2, theta1,
+                                          delta, cache, live, skip)
+        else:
+            anchor = stream_anchor_stats(
+                fc, y_key, lam1, theta1, delta=delta,
+                live_chunks=live_arg if skip else None,
+                cache=cache if skip else None)
+            if not skip:
+                # full-stream twin: the transfer happened for every chunk, but
+                # cache entries for dead chunks must NOT advance — identical
+                # cache evolution to the skipping run is what makes the two
+                # modes bitwise-comparable — so refresh the live set only.
+                cache.refresh(anchor,
+                              live=set(int(i) for i in np.nonzero(live)[0]))
+            if pure_vi:
+                red = FeatureReductions(d_theta=anchor.d_theta, d_one=d_one,
+                                        d_y=d_y, d_sq=d_sq)
+                sh = shared_scalars(y, lam1, lam2, theta1, delta=delta)
+                bounds = _finalize_bounds(red, sh)
+            else:
+                anchors = ((anchor,) if anchor_old is None
+                           else (anchor_old, anchor))
+                progs = tuple(PROGRAMS[nm] for nm in rules)
+                bounds = stack_bounds(progs, lam2, anchors, fixed)
+
+        if not bool(live.all()):
+            dead_feat = np.repeat(
+                ~live, np.diff(fc.offsets).astype(np.int64))
+            bounds = jnp.where(jnp.asarray(dead_feat), stale_bounds, bounds)
+        # NaN-safe keep: a non-finite bound certifies nothing — keep the
+        # feature
+        keep = ~(bounds < tau)
+        sp.set(live=int(np.count_nonzero(live)))
     return keep, bounds, anchor, live
 
 

@@ -39,7 +39,6 @@ solve transfer volume tracks the certified support, not ``m``.
 
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import jax
@@ -48,6 +47,7 @@ import numpy as np
 
 from repro.core.linalg import dot
 from repro.obs import trace as obs_trace
+from repro.obs.trace import span
 
 from repro.core.screening import (
     SAFE_TAU,
@@ -238,106 +238,109 @@ def fista_solve_chunked(
         return w_new, b_new, u_new, obj_new
 
     eps = float(jnp.finfo(fc.dtype).eps)
-    _tt0 = time.perf_counter()
-    while k < max_iters:
-        inv_Le = inv_L * backoff if guards else inv_L
-        t_next = 0.5 * (1.0 + float(jnp.sqrt(1.0 + 4.0 * t * t)))
-        beta = (t - 1.0) / t_next
-        zw = w + beta * (w - w_prev)
-        zb = b + beta * (b - b_prev)
-        uz = u + beta * (u - u_prev)
+    with span("stream.solve") as sp:
+        while k < max_iters:
+            inv_Le = inv_L * backoff if guards else inv_L
+            t_next = 0.5 * (1.0 + float(jnp.sqrt(1.0 + 4.0 * t * t)))
+            beta = (t - 1.0) / t_next
+            zw = w + beta * (w - w_prev)
+            zb = b + beta * (b - b_prev)
+            uz = u + beta * (u - u_prev)
 
-        w_new, b_new, u_new, obj_new = prox_from(zw, zb, uz, inv_Le)
-        restarted = float(obj_new) > float(obj)
-        if restarted:
-            # monotone restart: plain step from (w, b) — margins are carried
-            w_new, b_new, u_new, obj_new = prox_from(w, b, u, inv_Le)
-            t_next = 1.0
+            w_new, b_new, u_new, obj_new = prox_from(zw, zb, uz, inv_Le)
+            restarted = float(obj_new) > float(obj)
+            if restarted:
+                # monotone restart: plain step from (w, b) — margins are
+                # carried
+                w_new, b_new, u_new, obj_new = prox_from(w, b, u, inv_Le)
+                t_next = 1.0
 
-        if iteration_hook is not None:
-            hooked = iteration_hook(k, w_new, b_new, u_new, obj_new)
-            if hooked is not None:
-                w_new, b_new, u_new, obj_new = hooked
+            if iteration_hook is not None:
+                hooked = iteration_hook(k, w_new, b_new, u_new, obj_new)
+                if hooked is not None:
+                    w_new, b_new, u_new, obj_new = hooked
 
-        if guards:
-            obj_f = float(obj_new)
-            # a non-finite objective, or a *plain* (post-restart) step that
-            # still increased it beyond rounding noise: the step size is
-            # invalid — roll back, halve it, restart momentum (cf. the
-            # on-device guard in solver._make_fista_body)
-            bad = not np.isfinite(obj_f) or (
-                restarted and obj_f > float(obj)
-                + 256.0 * eps * max(abs(float(obj)), 1.0))
-            if bad:
-                health += 1
-                backoff *= 0.5
-                w_prev, b_prev, u_prev, t = w, b, u, 1.0
-                rel_prev = rel_prev2 = float("inf")
-                k += 1
-                if (health & (HEALTH_SCREEN_REFUSED - 1)) >= MAX_GUARD_TRIPS:
-                    break  # unrecoverable: poisoned operands (see solver)
-                continue
+            if guards:
+                obj_f = float(obj_new)
+                # a non-finite objective, or a *plain* (post-restart) step that
+                # still increased it beyond rounding noise: the step size is
+                # invalid — roll back, halve it, restart momentum (cf. the
+                # on-device guard in solver._make_fista_body)
+                bad = not np.isfinite(obj_f) or (
+                    restarted and obj_f > float(obj)
+                    + 256.0 * eps * max(abs(float(obj)), 1.0))
+                if bad:
+                    health += 1
+                    backoff *= 0.5
+                    w_prev, b_prev, u_prev, t = w, b, u, 1.0
+                    rel_prev = rel_prev2 = float("inf")
+                    k += 1
+                    trips = health & (HEALTH_SCREEN_REFUSED - 1)
+                    if trips >= MAX_GUARD_TRIPS:
+                        break  # unrecoverable: poisoned operands (see solver)
+                    continue
 
-        # restart iterations are not convergence evidence (cf. the in-core
-        # body): force one more plain iteration after every restart
-        rel = (float("inf") if restarted
-               else abs(float(obj) - float(obj_new)) / max(abs(float(obj)), 1e-30))
-        w_prev, b_prev, u_prev = w, b, u
-        w, b, u, obj, t = w_new, b_new, u_new, obj_new, t_next
-        k += 1
-        # three consecutive sub-tol iterations (see solver.FistaState.rel_prev)
-        if max(rel, rel_prev, rel_prev2) <= tol:
-            converged = True
-            break
-        rel_prev, rel_prev2 = rel, rel_prev
+            # restart iterations are not convergence evidence (cf. the in-core
+            # body): force one more plain iteration after every restart
+            rel = (float("inf") if restarted
+                   else abs(float(obj) - float(obj_new))
+                   / max(abs(float(obj)), 1e-30))
+            w_prev, b_prev, u_prev = w, b, u
+            w, b, u, obj, t = w_new, b_new, u_new, obj_new, t_next
+            k += 1
+            # three consecutive sub-tol iterations (see
+            # solver.FistaState.rel_prev)
+            if max(rel, rel_prev, rel_prev2) <= tol:
+                converged = True
+                break
+            rel_prev, rel_prev2 = rel, rel_prev
 
-        if dynamic and k % int(screen_every) == 0 and k < max_iters:
-            # segment boundary: certify the reduced problem's gap, screen
-            # the at-lambda region, AND into the live masks
-            theta, delta = gap_theta_delta_stream(
-                fc, y, w, b, lam, u=u,
-                live_chunks=live_arg, feature_mask=fmask_dev)
-            if not bool(jnp.isfinite(delta)):
-                # refused certificate (non-finite gap/theta, sanitized to
-                # delta = inf): screening from it could discard a live
-                # feature — fail-safe to keep-all for this segment
-                health |= HEALTH_SCREEN_REFUSED
-                continue
-            yt = y * theta
-            parts = []
-            for i in range(fc.n_chunks):
-                s, e = fc.chunk_bounds(i)
-                parts.append(jnp.zeros((e - s,), fc.dtype))
-            for (s, e), dev in fc.stream(live_chunks=live_arg):
-                i = int(np.searchsorted(fc.offsets[1:], s, side="right"))
-                parts[i] = (row_dot(dev, yt) if isinstance(dev, jnp.ndarray)
-                            else dev @ yt)
-            red = FeatureReductions(d_theta=jnp.concatenate(parts),
-                                    d_one=d_one, d_y=d_y, d_sq=d_sq)
-            sh = shared_scalars(y, lam, lam, theta, delta=delta)
-            # NaN-safe keep: a non-finite bound must KEEP its feature
-            keep = np.asarray(~(_finalize_bounds(red, sh) < screen_tau))
-            new_fmask = fmask & keep
-            n_screens += 1
-            obs_trace.instant("stream.solve.screen", iter=k,
-                              kept=int(new_fmask.sum()))
-            if new_fmask.sum() < fmask.sum():
-                fmask = new_fmask
-                masked = True
-                fmask_dev = jnp.asarray(fmask, fc.dtype)
-                live = _chunks_with_live_features(fc, fmask)
-                live_arg = None if live.all() else live
-                w = w * fmask_dev
-                u = fc.rmatvec(w, live_chunks=live_arg)
-                obj = _objective(_slacks(u, b, y, sm), w, lam)
-                # mask change invalidates momentum: restart cleanly
-                w_prev, b_prev, u_prev, t = w, b, u, 1.0
-                rel_prev = rel_prev2 = float("inf")
+            if dynamic and k % int(screen_every) == 0 and k < max_iters:
+                # segment boundary: certify the reduced problem's gap, screen
+                # the at-lambda region, AND into the live masks
+                theta, delta = gap_theta_delta_stream(
+                    fc, y, w, b, lam, u=u,
+                    live_chunks=live_arg, feature_mask=fmask_dev)
+                if not bool(jnp.isfinite(delta)):
+                    # refused certificate (non-finite gap/theta, sanitized to
+                    # delta = inf): screening from it could discard a live
+                    # feature — fail-safe to keep-all for this segment
+                    health |= HEALTH_SCREEN_REFUSED
+                    continue
+                yt = y * theta
+                parts = []
+                for i in range(fc.n_chunks):
+                    s, e = fc.chunk_bounds(i)
+                    parts.append(jnp.zeros((e - s,), fc.dtype))
+                for (s, e), dev in fc.stream(live_chunks=live_arg):
+                    i = int(np.searchsorted(fc.offsets[1:], s, side="right"))
+                    parts[i] = (row_dot(dev, yt)
+                                if isinstance(dev, jnp.ndarray)
+                                else dev @ yt)
+                red = FeatureReductions(d_theta=jnp.concatenate(parts),
+                                        d_one=d_one, d_y=d_y, d_sq=d_sq)
+                sh = shared_scalars(y, lam, lam, theta, delta=delta)
+                # NaN-safe keep: a non-finite bound must KEEP its feature
+                keep = np.asarray(~(_finalize_bounds(red, sh) < screen_tau))
+                new_fmask = fmask & keep
+                n_screens += 1
+                obs_trace.instant("stream.solve.screen", iter=k,
+                                  kept=int(new_fmask.sum()))
+                if new_fmask.sum() < fmask.sum():
+                    fmask = new_fmask
+                    masked = True
+                    fmask_dev = jnp.asarray(fmask, fc.dtype)
+                    live = _chunks_with_live_features(fc, fmask)
+                    live_arg = None if live.all() else live
+                    w = w * fmask_dev
+                    u = fc.rmatvec(w, live_chunks=live_arg)
+                    obj = _objective(_slacks(u, b, y, sm), w, lam)
+                    # mask change invalidates momentum: restart cleanly
+                    w_prev, b_prev, u_prev, t = w, b, u, 1.0
+                    rel_prev = rel_prev2 = float("inf")
+        sp.set(iters=k, converged=bool(converged), screens=n_screens,
+               kept=int(fmask.sum()))
 
-    if obs_trace.enabled():
-        obs_trace.complete("stream.solve", _tt0, time.perf_counter(),
-                           iters=k, converged=bool(converged),
-                           screens=n_screens, kept=int(fmask.sum()))
     if report is not None:
         report.update(
             screens=n_screens,

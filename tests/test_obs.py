@@ -11,8 +11,8 @@ from repro.core import PathDriver, svm_path
 from repro.data import make_sparse_classification
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.obs.path_trace import PathStep, PathTrace, build_path_trace
-from repro.obs.trace import NOOP_SPAN, Tracer
+from repro.obs.path_trace import PathStep, PathTrace
+from repro.obs.trace import Tracer
 from repro.sparse import FeatureChunked
 
 SOLVE = dict(tol=1e-9, max_iters=4000)
@@ -80,23 +80,23 @@ def test_span_set_attaches_attrs_mid_span(tracer):
 
 
 def test_disabled_mode_is_noop_singleton():
-    """Disabled tracing must allocate nothing on the hot path: span()
-    returns the shared no-op singleton and nothing is recorded."""
+    """A disabled recorder records nothing: spans (entered, attributed) and
+    instants leave its event list empty."""
     t = Tracer(enabled=False)
-    assert t.span("solve", step=1) is NOOP_SPAN
-    assert t.span("other") is NOOP_SPAN  # same object every call
-    with t.span("solve"):
+    with t.span("solve", step=1) as sp:
+        sp.set(iters=3)
         t.instant("marker")
-    t.add_complete_event("post", 0.0, 1.0)
+    with t.span("other"):
+        pass
     assert t.events == []
 
-    # module-level fast path honors the process tracer's switch
+    # module-level span honors the process tracer's switch
     was = obs_trace.enabled()
     obs_trace.disable()
     try:
-        assert obs_trace.span("x") is NOOP_SPAN
         n0 = len(obs_trace.get_tracer().events)
-        obs_trace.complete("x", 0.0, 1.0)
+        with obs_trace.span("x"):
+            pass
         obs_trace.instant("x")
         assert len(obs_trace.get_tracer().events) == n0
     finally:
@@ -141,7 +141,7 @@ def test_metric_kinds_and_dumps():
     assert snap["t.count"] == 5
     assert snap["t.gauge"] == 7
     assert snap["t.hist"] == {"count": 2, "sum": 4.0, "min": 1.0,
-                              "max": 3.0, "mean": 2.0}
+                              "max": 3.0, "mean": 2.0, "last": 3.0}
     # kind collisions are typed errors, not silent re-registration
     with pytest.raises(TypeError):
         obs_metrics.gauge("t.count")
@@ -150,6 +150,21 @@ def test_metric_kinds_and_dumps():
     prom = obs_metrics.to_prometheus()
     assert "repro_t_count_total 5" in prom
     assert "repro_t_hist_count 2" in prom
+    assert "repro_t_hist_last 3.0" in prom
+
+
+def test_histogram_last_is_the_newest_observation():
+    """``last`` follows the newest observation, not the extremes, and
+    empties with the histogram."""
+    h = obs_metrics.histogram("t.last")
+    assert h.get()["last"] is None
+    for v in (5.0, 1.0, 3.0):
+        h.observe(v)
+        assert h.get()["last"] == v
+    assert (h.get()["min"], h.get()["max"]) == (1.0, 5.0)
+    h.reset()
+    assert h.get()["last"] is None
+    assert "repro_t_last_last" not in obs_metrics.to_prometheus()
 
 
 def test_registry_mirrors_stream_stats_bitwise(ds):
@@ -249,21 +264,3 @@ def test_path_trace_chunked_engine(ds):
     _assert_schema(pt, 4)
     assert pt.walls_observed
     assert pt.meta["storage"] == "chunked"
-
-
-def test_path_trace_emits_synthesized_spans(ds):
-    """A single-dispatch engine's PathTrace must synthesize per-step spans
-    into an enabled tracer (Chrome 'X' events tiling the dispatch wall)."""
-    pt = build_path_trace(
-        "scan", [1.0, 0.5], [3, 5], None, [1, 2], [10, 20],
-        [0.5, 0.5], total_s=1.0, walls_observed=False)
-    t = Tracer(enabled=True)
-    pt.emit_to_tracer(t)
-    evs = [e for e in t.events if e["name"] == "scan.step"]
-    assert len(evs) == 2
-    # steps tile contiguously and end at the emit time
-    assert evs[0]["ts"] + evs[0]["dur"] == pytest.approx(evs[1]["ts"])
-    # a disabled tracer records nothing
-    t2 = Tracer(enabled=False)
-    pt.emit_to_tracer(t2)
-    assert t2.events == []
