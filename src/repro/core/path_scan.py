@@ -169,12 +169,17 @@ class ScanPathOutputs(NamedTuple):
     # (non-finite) certificate and fail-safed to keep-all. 0 = clean.
     health: jax.Array
     # (T,) int32 — certification rounds whose feasibility rescale was
-    # binding (solver.gap_theta_delta_binding), of n_feas_iters + 1 per step
+    # binding (solver.gap_theta_delta_binding), of at most n_feas_iters + 1
+    # per step
     feas_binding: jax.Array
+    # (T,) int32 — feasibility rounds (full sweeps of X) each step's
+    # certificate ran, at most n_feas_iters + 1
+    feas_rounds: jax.Array
 
 
-#: feasibility rounds of each step's certificate before its final rescale
-#: (``solver.gap_theta_delta_binding``)
+#: cap on the projecting feasibility rounds of each step's certificate; the
+#: rounds stop at the first whose rescale does not bind, so a step runs at
+#: most N_FEAS_ITERS + 1 (``solver.gap_theta_delta_binding``)
 N_FEAS_ITERS = 8
 
 
@@ -403,7 +408,7 @@ def _batched_path_step(
             scope="svm_path_batched/certify/feasibility")
 
     with jax.named_scope("svm_path_batched/certify"):
-        theta2, delta2, gap, feas_binding = jax.vmap(
+        theta2, delta2, gap, feas_binding, feas_rounds = jax.vmap(
             certify_one, in_axes=(ax, ax, ax, 0, 0, 0, 0))(
             X, y, sm, w2, b2, lam, u_fin)
 
@@ -414,7 +419,7 @@ def _batched_path_step(
         fmask=keep, cap=cap_used, resurrected=resurrected,
         health=health | jnp.where(
             anchor_ok, 0, HEALTH_SCREEN_REFUSED).astype(jnp.int32),
-        feas_binding=feas_binding,
+        feas_binding=feas_binding, feas_rounds=feas_rounds,
     )
     new_carry = (w2, b2, theta2, delta2, lam, fmask)
     if needs_hist:
@@ -725,10 +730,11 @@ def _path_scan_program(
         # (full-X certificate — the dual feasibility max runs over every
         # feature — but the margin sweep rides the solver's carried u)
         with jax.named_scope("svm_path/certify"):
-            theta2, delta2, gap, feas_binding = gap_theta_delta_binding(
-                X, y, w2, b2, lam, sm, n_feas_iters=n_feas_iters, col=col,
-                u=u_fin,
-            )
+            theta2, delta2, gap, feas_binding, feas_rounds = (
+                gap_theta_delta_binding(
+                    X, y, w2, b2, lam, sm, n_feas_iters=n_feas_iters,
+                    col=col, u=u_fin,
+                ))
 
         out = ScanPathOutputs(
             w=w2, b=b2, obj=obj,
@@ -741,7 +747,7 @@ def _path_scan_program(
             fmask=keep, cap=cap_used, resurrected=resurrected,
             health=health | jnp.where(
                 anchor_ok, 0, HEALTH_SCREEN_REFUSED).astype(jnp.int32),
-            feas_binding=feas_binding,
+            feas_binding=feas_binding, feas_rounds=feas_rounds,
         )
         new_carry = (w2, b2, theta2, delta2, lam, fmask)
         if needs_hist:
@@ -878,8 +884,13 @@ def _to_path_result(lambdas, outs: ScanPathOutputs, lam_max_val, wall_s,
     PathDriver._observe_run(engine, np.asarray(outs.kept, np.int64),
                             np.asarray(outs.health, np.int64))
     feas_binding = np.asarray(outs.feas_binding, np.int64)
+    feas_rounds = np.asarray(outs.feas_rounds, np.int64)
+    # both over the rounds' cap, so the binding share keeps its meaning
+    feas_cap = max(T * (N_FEAS_ITERS + 1), 1)
     obs_metrics.histogram("path.certify_binding_share").observe(
-        float(feas_binding.sum()) / max(T * (N_FEAS_ITERS + 1), 1))
+        float(feas_binding.sum()) / feas_cap)
+    obs_metrics.histogram("path.certify_rounds_share").observe(
+        float(feas_rounds.sum()) / feas_cap)
     return PathResult(
         lambdas=np.asarray(lambdas, np.float64),
         weights=np.asarray(outs.w, np.float64),
@@ -911,9 +922,10 @@ def _to_path_result(lambdas, outs: ScanPathOutputs, lam_max_val, wall_s,
             # per-step guard telemetry (solver.HEALTH_SCREEN_REFUSED flags a
             # fail-safe keep-all step; low bits count solver rollbacks)
             "health": np.asarray(outs.health, np.int64),
-            # per-step certification rounds whose rescale was binding, of
-            # N_FEAS_ITERS + 1
+            # per-step certification rounds whose rescale was binding, and
+            # the rounds run, each of at most N_FEAS_ITERS + 1
             "feas_binding": feas_binding,
+            "feas_rounds": feas_rounds,
             "options": dict(static_kw),
         },
     )
@@ -1127,7 +1139,7 @@ def svm_path_scan_sharded(
         # inside the body (solver._make_fista_body), so shards agree
         health=P(),
         # replicated: the rounds' maxima are pmax'd over the model axis
-        feas_binding=P(),
+        feas_binding=P(), feas_rounds=P(),
     )
     fn = jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                                out_specs=out_specs, check_vma=False))

@@ -639,8 +639,8 @@ def gap_theta_delta(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Gap-certified ``(theta1, delta, gap)`` at the current iterate.
 
-    :func:`gap_theta_delta_binding` with its count of binding rounds left
-    out; see there.
+    :func:`gap_theta_delta_binding` with its counts of binding rounds and
+    of rounds left out; see there.
     """
     return gap_theta_delta_binding(X, y, w, b, lam, sample_mask,
                                    n_feas_iters, col, u)[:3]
@@ -657,8 +657,9 @@ def gap_theta_delta_binding(
     col: Collectives = LOCAL,
     u: Optional[jax.Array] = None,
     scope: str = "feasibility",
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Gap-certified ``(theta1, delta, gap, binding)`` at the current iterate.
+) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Gap-certified ``(theta1, delta, gap, binding, rounds)`` at the current
+    iterate.
 
     The sample-masked generalization of ``dual.safe_theta_and_delta`` (same
     alternating feasibility projection, same 1-strong-concavity radius):
@@ -672,13 +673,22 @@ def gap_theta_delta_binding(
     iterate saves one full sweep of X. ``col`` binds the reductions to mesh
     collectives for ``shard_map`` blocks (see :class:`Collectives`).
 
-    ``binding`` (int32) counts the feasibility rounds, the final rescale
-    included (``n_feas_iters + 1`` in all), whose ``max_j |X_j^T (y*alpha)|``
-    exceeded ``lam`` by more than :data:`FEAS_BINDING_RTOL`, so that their
-    rescale moved alpha by more than the rounding of the max's own sweep.
-    It is read from the maxima the rounds compute anyway: no extra sweep.
-    The rounds run in the name scope ``scope``: under ``vmap`` the name
-    stack reads ``vmap(<scope>)``, so a batched caller passes its full path.
+    Each round sweeps ``max_j |X_j^T (y*alpha)|``, rescales alpha onto the
+    feasible box, then applies the equality projection if the rescale was
+    binding or the round is the first, and the round is below
+    ``n_feas_iters``. The loop ends after the first round that did not
+    project, so its last operation is a rescale by ``lam / max`` of exactly
+    the alpha whose max it measured: the certificate is feasible however
+    many rounds ran, and ``eq_resid`` covers what the projection left. At
+    most ``n_feas_iters + 1`` rounds run; ``rounds`` (int32) is how many did.
+
+    ``binding`` (int32) counts the rounds run whose max exceeded ``lam`` by
+    more than :data:`FEAS_BINDING_RTOL`, so that their rescale moved alpha by
+    more than the rounding of the max's own sweep; the same test decides
+    whether a round projects. Both are read from the maxima the rounds
+    compute anyway: no extra sweep. The rounds run in the name scope
+    ``scope``: under ``vmap`` the name stack reads ``vmap(<scope>)``, so a
+    batched caller passes its full path.
     """
     sm = sample_mask
     if u is None:
@@ -686,38 +696,34 @@ def gap_theta_delta_binding(
     xi = jnp.maximum(0.0, 1.0 - y * (u + b))
     if sm is not None:
         xi = xi * sm
-    alpha = xi
-    p_obj = col.psum_data(0.5 * jnp.sum(alpha * alpha)) + lam * col.psum_model(
+    p_obj = col.psum_data(0.5 * jnp.sum(xi * xi)) + lam * col.psum_model(
         jnp.sum(jnp.abs(w)))
     if sm is not None:
         n_eff = col.psum_data(jnp.sum(sm))
     else:
         n_eff = col.psum_data(jnp.asarray(float(y.shape[0]), X.dtype))
 
-    def corr_scale(alpha):
-        """``(scale, binding)``: the rescale of alpha onto the feasible
-        box, and whether it moves alpha (the max exceeds ``lam``)."""
+    def feas_round(carry):
+        """One round: rescale alpha onto the box, then project onto the
+        equality if the rescale was binding or the round is the first."""
+        r, alpha, binding, _ = carry
         corr = col.psum_data(dot(X, y * alpha))  # fhat_j^T alpha for all j
         mx = col.pmax_model(jnp.max(jnp.abs(corr)))
-        return (jnp.minimum(1.0, lam / jnp.maximum(mx, 1e-30)),
-                mx > lam * (1.0 + FEAS_BINDING_RTOL))
-
-    def body(alpha, _):
-        scale, binding = corr_scale(alpha)
-        alpha = alpha * scale
-        alpha = jnp.maximum(
+        bound = mx > lam * (1.0 + FEAS_BINDING_RTOL)
+        alpha = alpha * jnp.minimum(1.0, lam / jnp.maximum(mx, 1e-30))
+        project = (bound | (r == 0)) & (r < n_feas_iters)
+        proj = jnp.maximum(
             0.0, alpha - col.psum_data(dot(alpha, y)) / n_eff * y)
         if sm is not None:
-            alpha = alpha * sm
-        return alpha, binding
+            proj = proj * sm
+        return (r + 1, jnp.where(project, proj, alpha),
+                binding + bound.astype(jnp.int32), project)
 
     with jax.named_scope(scope):
-        alpha, bound = jax.lax.scan(body, alpha, None, length=n_feas_iters)
-        # final rescale so the inequality constraints hold for sure
-        scale, last_bound = corr_scale(alpha)
-        alpha = alpha * scale
-    binding = (jnp.sum(bound.astype(jnp.int32))
-               + last_bound.astype(jnp.int32))
+        zero = jnp.zeros((), jnp.int32)
+        rounds, alpha, binding, _ = jax.lax.while_loop(
+            lambda carry: carry[3], feas_round,
+            (zero, xi, zero, jnp.ones((), bool)))
     d_obj = col.psum_data(jnp.sum(alpha)) - 0.5 * col.psum_data(
         jnp.sum(alpha * alpha))
     gap = jnp.maximum(p_obj - d_obj, 0.0)
@@ -737,7 +743,7 @@ def gap_theta_delta_binding(
                & jnp.all(jnp.isfinite(theta)))
     inf = jnp.asarray(jnp.inf, X.dtype)
     return (theta, jnp.where(cert_ok, delta, inf),
-            jnp.where(cert_ok, gap, inf), binding)
+            jnp.where(cert_ok, gap, inf), binding, rounds)
 
 
 def _dynamic_run(

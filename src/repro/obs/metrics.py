@@ -16,8 +16,8 @@ Conventions: dotted lowercase names namespaced by subsystem —
 ``engine.cache.retraces`` — with counters for monotonic totals, gauges for
 last-observed values, histograms for per-event distributions
 (``serve.latency_s``, ``path.kept``, and one observation per call in
-``path.entry_s`` and ``path.certify_binding_share``, whose ``last`` the
-benchmark reads). Prometheus output maps dots to
+``path.entry_s``, ``path.certify_binding_share`` and
+``path.certify_rounds_share``, whose ``last`` the benchmark reads). Prometheus output maps dots to
 underscores (``repro_serve_hits_total``).
 
 Thread-safe: metric creation and increments take the registry/metric lock

@@ -130,35 +130,43 @@ def test_lambda_max_program_matches_the_eager_reduction(ds):
 
 
 def _recount(X, y, w, b, lam):
-    """The binding rounds of one step's certificate, recounted in plain
-    ``jnp`` from the returned ``w`` and ``b``."""
+    """``(binding, rounds)`` of one step's certificate, recounted in plain
+    ``jnp`` from the returned ``w`` and ``b``: each round rescales, projects
+    if it bound or is the first (and is below the cap), and the rounds stop
+    after the first that did not project."""
     X = jnp.asarray(X, jnp.float32)
     y = jnp.asarray(y, jnp.float32)
     alpha = jnp.maximum(0.0, 1.0 - y * (X.T @ jnp.asarray(w, jnp.float32)
                                          + jnp.float32(b)))
-    count = 0
+    binding = 0
     for r in range(N_FEAS_ITERS + 1):
         mx = jnp.max(jnp.abs(X @ (y * alpha)))
-        count += int(mx > lam * (1.0 + FEAS_BINDING_RTOL))
+        bound = bool(mx > lam * (1.0 + FEAS_BINDING_RTOL))
+        binding += int(bound)
         alpha = alpha * jnp.minimum(1.0, lam / mx)
-        if r < N_FEAS_ITERS:
-            alpha = jnp.maximum(0.0, alpha - jnp.dot(alpha, y) / y.size * y)
-    return count
+        if not ((bound or r == 0) and r < N_FEAS_ITERS):
+            return binding, r + 1
+        alpha = jnp.maximum(0.0, alpha - jnp.dot(alpha, y) / y.size * y)
+    raise AssertionError("the rounds never stopped")
 
 
 def test_feas_binding_matches_a_plain_recount(ds):
     """``extras["feas_binding"]`` counts, per step, the certificate's rounds
-    whose rescale was binding, as an independent recount on the returned path
-    does; the path's share of them lands in the registry."""
+    whose rescale was binding, and ``extras["feas_rounds"]`` the rounds it
+    ran, as an independent recount on the returned path does; the path's
+    shares of them, both over the rounds' cap, land in the registry."""
     r = svm_path(ds.X, ds.y, n_lambdas=T, engine="scan", reduce="compact",
                  **SOLVE)
-    got = r.extras["feas_binding"]
+    binding, rounds = r.extras["feas_binding"], r.extras["feas_rounds"]
     want = [_recount(ds.X, ds.y, r.weights[k], r.biases[k],
                      np.float32(r.lambdas[k])) for k in range(T)]
-    assert got.tolist() == want
-    assert 0 < got.sum() < T * (N_FEAS_ITERS + 1)
-    h = obs_metrics.snapshot()["path.certify_binding_share"]
-    assert h["last"] == got.sum() / (T * (N_FEAS_ITERS + 1))
+    assert binding.tolist() == [bd for bd, _ in want]
+    assert rounds.tolist() == [rd for _, rd in want]
+    cap = T * (N_FEAS_ITERS + 1)
+    assert 0 < binding.sum() < rounds.sum() < cap
+    snap = obs_metrics.snapshot()
+    assert snap["path.certify_binding_share"]["last"] == binding.sum() / cap
+    assert snap["path.certify_rounds_share"]["last"] == rounds.sum() / cap
 
 
 def test_entry_self_time_is_observed_per_call(ds):
@@ -187,6 +195,7 @@ def _reader(name):
 @pytest.mark.parametrize("name,histogram,scale", [
     ("entry_ms", "path.entry_s", 1e3),
     ("feas_binding", "path.certify_binding_share", 100.0),
+    ("feas_rounds", "path.certify_rounds_share", 100.0),
 ])
 def test_bench_reader_reads_the_last_call(ds, monkeypatch, name, histogram,
                                           scale):

@@ -8,6 +8,7 @@ except ImportError:  # optional dev dep; see tests/_hyp_compat.py + pyproject
     from _hyp_compat import given, settings, st
 
 from repro.core import fista_solve, lambda_max, lipschitz_estimate, primal_objective
+from repro.core import solver
 from repro.data import make_sparse_classification
 
 
@@ -85,3 +86,44 @@ def test_solution_agrees_with_scipy_reference(seed, ratio):
                         options={"maxiter": 5000, "ftol": 1e-14})
     ours = float(primal_objective(X, y, res.w, res.b, lam))
     assert ours <= out.fun + 1e-3 * max(1.0, abs(out.fun))
+
+
+@pytest.fixture(scope="module")
+def feas_problem():
+    """A problem at half its lambda_max, a converged iterate of it, and the
+    dual optimum from a tight solve."""
+    ds = make_sparse_classification(m=120, n=90, seed=21)
+    X, y = jnp.asarray(ds.X), jnp.asarray(ds.y)
+    lam = jnp.float32(0.5 * float(lambda_max(X, y)))
+    solved = fista_solve(X, y, lam, max_iters=5000, tol=1e-9)
+    tight = fista_solve(X, y, lam, max_iters=80000, tol=1e-15)
+    theta_ref = jnp.maximum(0.0, 1.0 - y * (X.T @ tight.w + tight.b)) / lam
+    points = {"zero": (jnp.zeros((X.shape[0],), X.dtype), jnp.float32(0.0)),
+              "solved": (solved.w, solved.b)}
+    return X, y, lam, points, theta_ref
+
+
+@pytest.mark.parametrize("forced,n_feas_iters,point,want_rounds", [
+    *[(True, n, p, n + 1) for n in (0, 1, 8) for p in ("zero", "solved")],
+    (False, 8, "zero", 2),
+    (False, 8, "solved", 2),
+])
+def test_feasibility_rounds_stop_early_within_the_cap_and_stay_safe(
+        feas_problem, monkeypatch, forced, n_feas_iters, point, want_rounds):
+    """The certificate's rounds stop after the first that does not project.
+    Forced to bind every round (a negative binding tolerance), they run the
+    cap, ``n_feas_iters + 1``; left alone, the first round binds and the
+    second finds the max at ``lam``. Either way the certificate is
+    feasible and its radius covers the distance to the dual optimum."""
+    X, y, lam, points, theta_ref = feas_problem
+    if forced:
+        monkeypatch.setattr(solver, "FEAS_BINDING_RTOL", -0.5)
+    w, b = points[point]
+    theta, delta, _gap, binding, rounds = solver.gap_theta_delta_binding(
+        X, y, w, b, lam, n_feas_iters=n_feas_iters)
+    assert int(rounds) == want_rounds
+    assert int(binding) == (want_rounds if forced else 1)
+    assert float(jnp.min(theta)) >= 0.0
+    corr = np.asarray(X @ (y * theta * lam))
+    assert np.max(np.abs(corr)) <= float(lam) * (1 + 1e-6)
+    assert float(delta) >= float(jnp.linalg.norm(theta - theta_ref))
