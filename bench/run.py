@@ -24,11 +24,23 @@ compared are printed beside their limits: as the last lines on standard
 error, and under ``checks``, the last key of the result. The result is the
 last line on standard output.
 
+A reader returns the metric's value, or ``None`` where the run gave it
+nothing to read; it raises ``LookupError`` where the program under test has
+no counter or scope of the kind it reads at all. Such a metric is left out
+of the result, with a ``[metric] <name> absent from this program`` line, so
+a cell can list a metric that a later program adds.
+
 Without a TPU, on fewer chips than the cell asks for, on a chip missing
 from ``bench/peaks.json``, with ``REPRO_PALLAS_INTERPRET`` set or with
 ``REPRO_FISTA_PALLAS=0``, it prints no result and exits 1; so it does when a
-per-layer metric that ``BENCHMARK.json`` lists for the cell finds nothing to
-read in the trace.
+reader of a per-layer metric that ``BENCHMARK.json`` lists for the cell
+returns ``None``.
+
+A configuration's file may carry other keys than those read here:
+``cpu_shapes`` (``{"tiny": shape, "mid": shape, "mid_limits": limits}``,
+``mid`` and ``mid_limits`` optional) sizes it for the CPU rehearsal,
+``test_bench.py``. So a new configuration, its cells and its metrics enter
+the benchmark as new files and new entries of ``BENCHMARK.json`` alone.
 
 Two options serve the readings limits are set from, never the benchmark's
 own runs: ``--entry control_high`` drives the lower-precision control in the
@@ -324,7 +336,12 @@ def _run(args, bench, cells, wl, cfg, hlo_dir, chip_check) -> dict:
     if args.trace:
         ctx = SimpleNamespace(paths=summaries, trace=tr, peaks=peaks)
         for m in per_layer_metrics(bench, args.workload):
-            v = load_module(BENCH / "metrics" / f"{m['name']}.py").read(ctx)
+            reader = load_module(BENCH / "metrics" / f"{m['name']}.py")
+            try:
+                v = reader.read(ctx)
+            except LookupError:
+                say(f"[metric] {m['name']} absent from this program")
+                continue
             if v is None:
                 raise Refusal(f"per-layer metric {m['name']} found nothing "
                               f"to read in this run of {args.workload}")

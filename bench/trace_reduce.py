@@ -9,7 +9,9 @@ host. Within it, for each device plane of the chips in use:
   line;
 * device time per scope sums the ops with no other op inside them (an op
   that contains others, such as a loop, is counted through its children),
-  each under the first of ``SCOPES`` that its framework name carries;
+  each under its deepest name scope (:func:`scope_of`) and under every
+  scope that holds that one: ``svm_path/solve`` counts the ops of
+  ``svm_path/solve/compact`` too. Ops under no scope count as ``other``;
 * an idle gap is a stretch of the window in which no op runs; it is named
   by the benchmark span that holds its middle.
 
@@ -32,8 +34,11 @@ import os
 import re
 from collections import defaultdict
 
-#: name-scope paths the path engine marks its phases with
-SCOPES = ("svm_path/screen", "svm_path/solve", "svm_path/certify")
+#: the name scope under which the path engine opens its phases' scopes
+ROOT_SCOPE = "svm_path"
+#: components of a name stack that JAX adds itself, not the program
+_JAX_SEGMENT = re.compile(r"^(?:while|body|cond|closed_call|branch_\d+_fun"
+                          r"|.*\(.*)$")
 #: the benchmark's own host spans
 SPANS = ("bench.path", "bench.between_paths")
 OPS_LINE = "XLA Ops"
@@ -56,17 +61,45 @@ def find_xplane(trace_dir: str) -> str:
     return found[-1]
 
 
-def _scope(text: str) -> str | None:
-    for sc in SCOPES:
-        if sc in text:
-            return sc
+def scope_of(op_name: str) -> str | None:
+    """The deepest name scope under :data:`ROOT_SCOPE` in an ``op_name``,
+    else ``None``: the components after its last ``svm_path``, less JAX's
+    own (``while``, ``body``, ``cond``, ``branch_<i>_fun``, ``jit(...)``,
+    ...) and the trailing primitive. Where XLA merged several names
+    (``a;b``), the first that has the root scope counts.
+    ``.../svm_path/solve/cond/branch_0_fun/svm_path/solve/compact/gather``
+    -> ``svm_path/solve/compact``."""
+    for name in op_name.split(";"):
+        parts = name.split("/")
+        if ROOT_SCOPE not in parts:
+            continue
+        last = len(parts) - 1 - parts[::-1].index(ROOT_SCOPE)
+        kept = [p for p in parts[last + 1:-1] if not _JAX_SEGMENT.match(p)]
+        if kept:
+            return "/".join([ROOT_SCOPE, *kept])
     return None
+
+
+def enclosing(scope: str) -> list[str]:
+    """``svm_path/solve/compact`` -> ``[svm_path/solve,
+    svm_path/solve/compact]``: the scope and each one holding it, below
+    the root."""
+    parts = scope.split("/")
+    return ["/".join(parts[:i]) for i in range(2, len(parts) + 1)] \
+        if parts[0] == ROOT_SCOPE else [scope]
+
+
+def phase(scope: str) -> str:
+    """The top-level phase of a scope (``svm_path/solve/compact`` ->
+    ``svm_path/solve``)."""
+    return enclosing(scope)[0]
 
 
 def module_scopes(hlo_text: str) -> tuple[str, dict, set]:
     """``(module name, {instruction: scope}, instructions)`` of one
     optimized HLO module in text form; the map holds the instructions that
-    fall under one of :data:`SCOPES`."""
+    fall under a scope (:func:`scope_of`): their own ``op_name``'s, else
+    the first one among the instructions they call."""
     module = ""
     own, calls, members = {}, {}, defaultdict(list)
     comp = None
@@ -78,7 +111,7 @@ def module_scopes(hlo_text: str) -> tuple[str, dict, set]:
         if m:
             name = m.group(1)
             op = _OP_NAME.search(line)
-            own[name] = _scope(op.group(1)) if op else None
+            own[name] = scope_of(op.group(1)) if op else None
             calls[name] = [c for g in _CALLS.findall(line)
                            for c in _NAME.findall(g)]
             members[comp].append(name)
@@ -230,8 +263,9 @@ def reduce(pd, device_ids=None, hlo=None) -> dict:
         maps = _scope_maps(ops, hlo)
         for s, e, name, module in _leaves(ops):
             sc = maps[module].get(instruction_of(name)) or "other"
-            scopes[sc] += (e - s) * 1e-9
-            by_op[f"{sc}:{op_label(name)}"] += (e - s) * 1e-9
+            for outer in enclosing(sc):
+                scopes[outer] += (e - s) * 1e-9
+            by_op[f"{phase(sc)}:{op_label(name)}"] += (e - s) * 1e-9
         merged = _union([(s, e) for s, e, _, _ in ops])
         busy += sum(e - s for s, e in merged) * 1e-9
         edges = [w0] + [x for iv in merged for x in iv] + [w1]
@@ -265,6 +299,18 @@ def span_at(t: float, spans) -> str:
         if s <= t <= e and (best is None or e - s < best[1] - best[0]):
             best = (s, e, name)
     return best[2] if best else "outside"
+
+
+def scope_ms(trace: dict | None, scope: str) -> float | None:
+    """Device milliseconds per traced path under ``scope``, for a metric's
+    reader: ``None`` where the trace holds no device op to read, and
+    ``LookupError`` where device ops ran but none under ``scope`` (the
+    program does not open it)."""
+    if trace is None or trace["paths"] == 0 or trace["planes"] == 0:
+        return None
+    if scope not in trace["scopes"]:
+        raise LookupError(f"no device op ran under {scope}")
+    return 1e3 * trace["scopes"][scope] / trace["paths"]
 
 
 def reduce_dir(trace_dir: str, devices=None, hlo_dir=None) -> dict:
